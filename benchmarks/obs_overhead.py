@@ -88,6 +88,9 @@ _ARGS.add_argument("--json", default=os.path.join(
 if __name__ == "__main__":
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     _early = _ARGS.parse_args()
+    from repro.launch.hostdev import use_compile_cache
+
+    use_compile_cache()
 
 import numpy as np
 

@@ -55,9 +55,10 @@ if __name__ == "__main__":
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     _early = _ARGS.parse_args()
     # the device fan-out must precede the first jax import
-    from repro.launch.hostdev import force_host_devices
+    from repro.launch.hostdev import force_host_devices, use_compile_cache
 
     force_host_devices(_early.shards)
+    use_compile_cache()
 
 import numpy as np
 

@@ -56,10 +56,11 @@ def _parse():
 
 if __name__ == "__main__":
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-    from repro.launch.hostdev import force_host_devices
+    from repro.launch.hostdev import force_host_devices, use_compile_cache
 
     _early = _parse()
     force_host_devices(max(s * r for s, r in _early.cells))
+    use_compile_cache()
 
 import time
 

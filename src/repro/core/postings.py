@@ -40,15 +40,17 @@ class Postings(NamedTuple):
 
 
 def build_postings(codes: jnp.ndarray) -> Postings:
-    """codes: (d, C) -> Postings.  Pure JAX; runs under jit."""
+    """codes: (d, C) -> Postings.  Pure JAX; runs under jit.
+
+    One stable sort of every column with its doc ids riding along as the
+    payload: the sort returns both posting tables directly, in their
+    (C, d) layout, with no gather and no transposed copies."""
     d, _ = codes.shape
-    order = jnp.argsort(codes, axis=0, stable=True)          # (d, C)
-    sorted_codes = jnp.take_along_axis(codes, order, axis=0)  # (d, C)
-    return Postings(
-        post_docs=order.T.astype(jnp.int32),
-        post_codes=sorted_codes.T,
-        n_docs=d,
-    )
+    cols = codes.T                                           # (C, d)
+    ids = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 1)
+    post_codes, post_docs = jax.lax.sort(
+        (cols, ids), dimension=1, num_keys=1, is_stable=True)
+    return Postings(post_docs=post_docs, post_codes=post_codes, n_docs=d)
 
 
 def _searchsorted_row(row: jnp.ndarray, value: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -13,11 +13,42 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-__all__ = ["normalize", "exact_scores", "rerank_topk", "brute_force_topk"]
+__all__ = ["normalize", "pairwise_sum", "exact_scores", "rerank_topk",
+           "brute_force_topk", "EXACT"]
+
+# Precision of every exact-cosine matmul (the rescore and the brute-force
+# gold standard).  A TPU multiplies f32 in bf16 passes by default; HIGHEST
+# keeps these scores fp32.  Candidate selection keeps the default.
+EXACT = jax.lax.Precision.HIGHEST
+
+
+def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum the last axis with a fixed pairwise tree: zero-pad it to a power
+    of two, then repeatedly add the upper half onto the lower.  The order
+    of the additions is a function of the axis length alone, so a row sums
+    to the same bits however many rows are computed with it -- ``jnp.sum``
+    lets XLA choose the order per tensor shape."""
+    n = x.shape[-1]
+    p2 = 1 << max(n - 1, 0).bit_length()                 # next power of two
+    if p2 != n:
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, p2 - n),))
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
 
 
 def normalize(x: jnp.ndarray, eps: float = 1e-12) -> jnp.ndarray:
-    return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), eps)
+    """Unit rows.  The norm sums through :func:`pairwise_sum`, so a row
+    normalizes to the same bits in a shard's block as in the whole table
+    (the sharded build's parity with the single-device build rests on
+    it)."""
+    # max(., 0) is exact for a square, and keeps the CPU backend from
+    # contracting the squares into the tree's adds as FMAs, which it does
+    # for some rows of a block and not others
+    sq = jnp.maximum(x * x, 0.0)
+    norm = jnp.sqrt(pairwise_sum(sq))[..., None]
+    return x / jnp.maximum(norm, eps)
 
 
 def exact_scores(vectors: jnp.ndarray, ids: jnp.ndarray,
@@ -31,7 +62,7 @@ def exact_scores(vectors: jnp.ndarray, ids: jnp.ndarray,
     search bit-identical (dist/shard_index.py merges through it too).
     """
     return jnp.einsum("qkn,qn->qk", vectors[ids], queries,
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.float32, precision=EXACT)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -44,7 +75,8 @@ def rerank_topk(
     """Exact cosine top-k among the candidates -> (ids (Q,k), scores (Q,k))."""
     cand = vectors[cand_ids]                            # (Q, page, n)
     scores = jnp.einsum(
-        "qpn,qn->qp", cand, queries, preferred_element_type=jnp.float32
+        "qpn,qn->qp", cand, queries, preferred_element_type=jnp.float32,
+        precision=EXACT,
     )
     _, top_pos = jax.lax.top_k(scores, k)
     top_ids = jnp.take_along_axis(cand_ids, top_pos, axis=1)
@@ -74,7 +106,7 @@ def brute_force_topk(
     def body(carry, inp):
         best_s, best_i = carry
         blk, base = inp
-        s = queries @ blk.T                              # (Q, block)
+        s = jnp.matmul(queries, blk.T, precision=EXACT)  # (Q, block)
         ids = base + jnp.arange(block, dtype=jnp.int32)
         valid = ids < d
         s = jnp.where(valid[None, :], s, -jnp.inf)

@@ -134,7 +134,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.encoding import Encoder, RoundingEncoder
 from repro.obs.compile_watch import watch_region
@@ -143,7 +143,7 @@ from repro.core.filtering import (BestFilter, TrimFilter, expand_mask,
 from repro.core.postings import (Postings, build_postings, code_df,
                                  df_lookup, idf_weights)
 from repro.core.quantize import quantize_rows
-from repro.core.rerank import normalize
+from repro.core.rerank import EXACT, normalize
 from repro.core.search import (_SENTINEL, FUSED_ENGINES, VectorIndex,
                                phase1_engine_scores)
 
@@ -477,7 +477,7 @@ class ShardedVectorIndex:
         if R == 1:
             return self
         devs = np.asarray(self.mesh.devices)[:, g]
-        sub = Mesh(devs, (DATA_AXIS,))
+        sub = Mesh(devs, (DATA_AXIS,), axis_types=(AxisType.Auto,))
         put = lambda x, spec: jax.device_put(x, NamedSharding(sub, spec))
         return dataclasses.replace(
             self, mesh=sub,
@@ -1368,7 +1368,7 @@ def _rescore(cvec, q, top_ids):
     """exact_scores' canonical (Q, k, n) einsum over pre-fetched hits;
     unfillable (id -1) slots stay -inf instead of a junk-row cosine."""
     s = jnp.einsum("qkn,qn->qk", cvec, q,
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32, precision=EXACT)
     return jnp.where(top_ids < 0, -jnp.inf, s)
 
 
@@ -1575,7 +1575,7 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
             vec_all, live_all = vec, lv
         cvec = vec_all[cand]                        # (Q, page_loc, n)
         s2 = jnp.einsum("qpn,qn->qp", cvec, q,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32, precision=EXACT)
         s2 = jnp.where(live_all[cand], s2, -jnp.inf)
         gid = (gid_all[cand] if (segs or G)
                else (cand + off).astype(jnp.int32))

@@ -1,16 +1,6 @@
-"""Version-portable ``shard_map``.
-
-jax >= 0.6 exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)``
-with the *complement* convention for partial-manual axes.  Callers say which
-axes they want manual; the adapter speaks whichever dialect is present.
-
-One deliberate degradation: 0.4.x partial-manual regions hard-crash XLA's
-SPMD partitioner (``Check failed: target.IsManualSubgroup() ==
-sharding().IsManualSubgroup()``), so on that branch the region is always
-fully manual -- axes the caller wanted AUTO become unreferenced manual axes,
-i.e. the computation replicates across them instead of staying sharded.
-Correct, just less parallel; newer jax gets the real partial-manual form.
+"""``shard_map`` with the repo's calling convention: callers name the axes
+they want manual (default: every mesh axis) and whether to check
+replication, and get ``jax.shard_map(..., axis_names=..., check_vma=...)``.
 """
 
 from __future__ import annotations
@@ -23,15 +13,7 @@ __all__ = ["shard_map"]
 def shard_map(f, mesh, in_specs, out_specs, manual_axes=None, check=False):
     """``shard_map`` manual over ``manual_axes`` (default: every mesh axis)."""
     manual = frozenset(manual_axes or mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check, axis_names=manual,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # 0.4.x: full manual only (see module docstring)
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
+        check_vma=check, axis_names=manual,
     )

@@ -56,9 +56,10 @@ def _pad_docs(arrs, live, d, block_d):
 
 def _finish(scores, ids, Q, d):
     """Slice off query padding and clamp ids in-range (-inf slots may
-    carry a padded doc id; everything downstream masks them by score,
-    but an out-of-range id must never escape)."""
-    return scores[:Q], jnp.minimum(ids[:Q], d - 1)
+    carry a padded doc id or the kernel's negative placeholder;
+    everything downstream masks them by score, but an out-of-range id
+    must never escape)."""
+    return scores[:Q], jnp.clip(ids[:Q], 0, d - 1)
 
 
 def _score_tile_codes(blk, qfree):
@@ -144,9 +145,9 @@ def fused_phase1(
     pad_q = (-Q) % block_q
     qc = jnp.pad(qcodes, ((0, pad_q), (0, 0)))
     w = jnp.pad(col_weights, ((0, pad_q), (0, 0)))
-    (dc,), lv = _pad_docs([doc_codes], lv, d, block_d)
-    s, i = fused_phase1_pallas(dc, qc, w, lv, page=page, block_q=block_q,
-                               block_d=block_d, interpret=not on_tpu)
+    s, i = fused_phase1_pallas(doc_codes, qc, w, lv, page=page,
+                               block_q=block_q, block_d=block_d,
+                               interpret=not on_tpu)
     return _finish(s, i, Q, d)
 
 
@@ -181,9 +182,7 @@ def fused_phase1_quant(
     pad_q = (-Q) % block_q
     q = jnp.pad(queries, ((0, pad_q), (0, 0)))
     qs = jnp.pad(qsum, ((0, pad_q), (0, 0)))
-    (d8, sc, zp), lv = _pad_docs(
-        [qcodes8, scale[:, None], zero[:, None]], lv, d, block_d)
     s, i = fused_phase1_quant_pallas(
-        d8, sc[:, 0], zp[:, 0], q, qs, lv, page=page, block_q=block_q,
+        qcodes8, scale, zero, q, qs, lv, page=page, block_q=block_q,
         block_d=block_d, interpret=not on_tpu)
     return _finish(s, i, Q, d)

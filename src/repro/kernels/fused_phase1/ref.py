@@ -9,7 +9,8 @@ full (Q, d) phase-1 score matrix, mask dead rows, then one global stable
 :func:`match_scores` is the ONE scoring expression the whole fp32 family
 shares (this oracle, the Pallas kernel body, the streaming fallback, and
 the sharded generation scorer): select then a MANUAL pairwise-tree sum
-over the code columns, zero-padded to a power of two.  Every tree step is
+over the code columns, zero-padded to a power of two
+(:func:`repro.core.rerank.pairwise_sum`).  Every tree step is
 an elementwise add of two halves, so the reduction order is a pure
 function of C -- the bits cannot depend on how the doc or query axis is
 tiled.  A ``jnp.sum`` over C does NOT have that property: XLA picks the
@@ -28,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantize import quantized_scores
+from repro.core.rerank import pairwise_sum
 
 
 def match_scores(doc_codes: jnp.ndarray,    # (d, C) int
@@ -39,21 +41,14 @@ def match_scores(doc_codes: jnp.ndarray,    # (d, C) int
     tiling (see module doc)."""
     x = jnp.where(qcodes[:, None, :] == doc_codes[None, :, :],
                   col_weights[:, None, :], 0.0)          # (Q, d, C)
-    n = x.shape[-1]
-    p2 = 1 << max(n - 1, 0).bit_length()                 # next power of two
-    if p2 != n:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, p2 - n)))
-    while x.shape[-1] > 1:
-        h = x.shape[-1] // 2
-        x = x[..., :h] + x[..., h:]
-    return x[..., 0]
+    return pairwise_sum(x)
 
 
 def _mask_topk(scores: jnp.ndarray, live: Optional[jnp.ndarray],
                page: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     if live is not None:
         scores = jnp.where(live[None, :], scores, -jnp.inf)
-    top_s, top_i = jax.lax.top_k(scores, page)
+    top_s, top_i = jax.lax.top_k(scores, min(page, scores.shape[1]))
     return top_s, top_i.astype(jnp.int32)
 
 

@@ -1,18 +1,26 @@
-"""Fan one CPU host out into N virtual jax devices -- jax-free on purpose.
+"""Process set-up for entry points -- jax-free on purpose.
 
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` only takes effect
-when set before the first jax import, so entry points call this at the very
-top of the module, ahead of any repro/jax import.  Both CLI front-ends
-(repro.launch.serve, benchmarks.shard_scale) share this one copy.
+when set before the first jax import, so entry points call
+:func:`force_host_devices` at the very top of the module, ahead of any
+repro/jax import.  :func:`use_compile_cache` points JAX's persistent
+compilation cache at one fixed directory, so a second run of the same
+programs skips their compiles.  The CLI front-ends (repro.launch.serve,
+chip_smoke.py, the benchmarks) share this one copy.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 
-__all__ = ["force_host_devices", "peek_int_arg"]
+__all__ = ["force_host_devices", "peek_int_arg", "use_compile_cache"]
 
 _FLAG = "xla_force_host_platform_device_count"
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# the checkout root: src/repro/launch/hostdev.py -> three levels up
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def force_host_devices(n: int) -> None:
@@ -35,3 +43,22 @@ def peek_int_arg(argv, name: str) -> int:
         except (IndexError, ValueError):
             return 0
     return 0
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    A ``JAX_COMPILATION_CACHE_DIR`` already in the environment wins and
+    nothing is set.  Otherwise the cache lives at ``<checkout>/.jax_cache``
+    (gitignored): a fixed path, because the path is part of the cache key.
+    The choice goes into the environment, so child processes share the
+    cache, and into jax's config when jax is already imported."""
+    path = os.environ.get(_CACHE_ENV)
+    if path:
+        return path
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    os.environ[_CACHE_ENV] = path
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -81,7 +81,8 @@ import time
 # --shards x --replicas needs S*R host devices, and XLA_FLAGS must be set
 # before the first jax import (which the repro.core import below triggers);
 # malformed values fall through to argparse, which owns the error message
-from repro.launch.hostdev import force_host_devices, peek_int_arg
+from repro.launch.hostdev import (force_host_devices, peek_int_arg,
+                                 use_compile_cache)
 
 force_host_devices(peek_int_arg(sys.argv, "--shards")
                    * max(peek_int_arg(sys.argv, "--replicas"), 1))
@@ -96,6 +97,7 @@ from repro.serve.engine import BatchedSearchEngine
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--docs", type=int, default=10000)
     ap.add_argument("--features", type=int, default=128)

@@ -6,10 +6,8 @@ buffers across the data axis (the dominant memory term of the llama4 train
 cell, immune to sharding constraints -- iteration A4).
 
 Here the dispatch runs under ``shard_map`` (via :mod:`repro.dist.shmap`),
-manual over the data axes with the model axis AUTO on jax >= 0.6 (on 0.4.x
-the adapter degrades to fully-manual -- partial-manual regions hard-crash
-that SPMD partitioner -- so expert weights replicate across ``model``
-there): every data shard sorts and buckets ONLY its local tokens into a
+manual over the data axes with the model axis AUTO: every data shard
+sorts and buckets ONLY its local tokens into a
 local capacity buffer (E, C_local, D), computes its experts, and combines
 locally.  Token
 buffers never cross data shards; the only cross-shard traffic is the

@@ -167,7 +167,8 @@ class Histogram(_Instrument):
             for i in idx:
                 self._counts[i] += 1
             self._n += len(xs)
-            self._sum += sum(xs)
+            for x in xs:       # observe()'s order: sum() compensates
+                self._sum += x
             lo, hi = min(xs), max(xs)
             if lo < self._min:
                 self._min = lo

@@ -1,11 +1,8 @@
-"""Test bootstrap: fall back to the vendored deterministic hypothesis shim
-(tests/_stubs/) when the real package is absent -- the container has no
-network, and property tests degrade gracefully to seeded random sampling."""
+"""Test bootstrap: property tests replay one fixed example sequence
+(derandomized, no example database), so every run draws the same cases
+and nothing is written into the checkout."""
 
-import os
-import sys
+from hypothesis import settings
 
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_stubs"))
+settings.register_profile("repro", derandomize=True, database=None)
+settings.load_profile("repro")

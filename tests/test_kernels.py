@@ -242,6 +242,23 @@ class TestFusedPhase1Kernel:
         for o in outs[1:]:
             _assert_fused_parity(o, outs[0], 300, "block invariance")
 
+    @pytest.mark.parametrize("block_d", [8, 24, 128])
+    def test_ties_across_tiles_keep_lowest_ids(self, block_d):
+        """Every live doc ties: the kernel's fold must keep the lowest
+        live ids, as one stable top-k over the dense matrix does, however
+        the ties fall across doc tiles."""
+        d, q, c, page = 100, 3, 6, 20
+        D = jnp.zeros((d, c), jnp.int8)
+        Q = jnp.zeros((q, c), jnp.int8)
+        W = jnp.ones((q, c), jnp.float32)
+        live = jnp.asarray(np.arange(d) % 3 != 1)
+        want = fused_phase1_ref(D, Q, W, page=page, live=live)
+        got = fp_ops.fused_phase1(D, Q, W, page=page, live=live,
+                                  block_d=block_d, force_pallas=True)
+        _assert_fused_parity(got, want, d, block_d)
+        live_ids = np.flatnonzero(np.asarray(live))[:page]
+        assert (np.asarray(got[1]) == live_ids[None, :]).all()
+
     def test_match_scores_doc_tile_invariance(self):
         """The load-bearing property underneath everything: scoring a doc
         slice yields the SAME bits as slicing the full score matrix, for

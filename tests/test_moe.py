@@ -49,7 +49,8 @@ from repro.models.transformer.moe_local import moe_ffn_local
 
 p = moe_init(jax.random.PRNGKey(0), 16, 32, 4, n_shared=1)
 x = jax.random.normal(jax.random.PRNGKey(1), (32, 16), jnp.float32)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     (jax.sharding.AxisType.Auto,) * 2)
 y_ref, a_ref = _moe_ffn_chunk(p, x, 2, 8.0, "silu")
 # per-shard capacity differs from global capacity; use cf large enough that
 # no drops happen either way -> outputs must match exactly

@@ -327,7 +327,7 @@ def test_recover_without_commit_raises(tmp_path):
 
 
 # ------------------------------------------------- crash-recovery property
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(n_docs=st.integers(8, 40), dims=st.integers(4, 12),
        n_ops=st.integers(1, 5), seed=st.integers(0, 2**20))
 def test_crash_recovery_bit_parity_sweep(n_docs, dims, n_ops, seed):
