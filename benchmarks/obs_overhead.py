@@ -19,8 +19,7 @@ shared index:
 * **full** -- everything v2 added on top of ``on``: a tail-sampled
   :class:`~repro.obs.slowlog.SlowLog` (every request gets a span
   skeleton), a :class:`~repro.obs.compile_watch.CompileWatch` wrapping
-  the dispatch seams (which now also captures per-program FLOP/byte
-  cost analysis at compile time), and ``profile=True`` on every submit
+  the dispatch seams, and ``profile=True`` on every submit
   (per-phase ``block_until_ready`` fences + a profile tree per
   request); v3 adds a concurrent 50ms poller hammering the device-side
   surfaces while the pass serves (``device_bytes`` + ``node_stats`` +
@@ -190,9 +189,8 @@ def run(n_docs=8000, n_features=64, n_queries=32, batch_size=16, page=320,
     # v3: the full config also pays the DEVICE-side plane while serving --
     # a concurrent poller hitting the index byte accounting, the engine
     # stats rollup, and the per-device node_stats every 50ms (still
-    # ~200x a production scrape cadence), plus compile-time cost capture
-    # riding the CompileWatch.  The <5% bar therefore covers the WHOLE
-    # plane, polled hot.
+    # ~200x a production scrape cadence).  The <5% bar therefore covers
+    # the WHOLE plane, polled hot.
     from repro.obs import device_bytes, node_stats
 
     def _poll_full(_eng=engines["full"]):

@@ -138,6 +138,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.encoding import Encoder, RoundingEncoder
 from repro.obs.compile_watch import watch_region
+from repro.obs.tracing import annotation
 from repro.core.filtering import (BestFilter, TrimFilter, expand_mask,
                                   feature_mask, index_best_codes)
 from repro.core.postings import (Postings, build_postings, code_df,
@@ -1051,51 +1052,52 @@ class ShardedVectorIndex:
         """
         if merge not in ("gather", "stream"):
             raise ValueError(f"unknown merge transport {merge!r}")
-        t_prof = time.monotonic() if profile is not None else 0.0
-        R = self.n_replicas
-        if live_groups is None:
-            groups = tuple(range(R))
-        else:
-            groups = tuple(sorted({int(g) for g in live_groups}))
-            if not groups or groups[0] < 0 or groups[-1] >= R:
-                raise ValueError(
-                    f"live_groups must be a non-empty subset of [0, {R}), "
-                    f"got {live_groups}")
-        U = len(groups)
-        queries = jnp.atleast_2d(queries)
-        page = min(page, self.n_ids)
-        k = min(k, page)
-        page_loc = min(page, self.docs_per_shard + self.seg_capacity
-                       + sum(s.width for s in self.segments))
+        with annotation("repro.search.encode"):
+            t_prof = time.monotonic() if profile is not None else 0.0
+            R = self.n_replicas
+            if live_groups is None:
+                groups = tuple(range(R))
+            else:
+                groups = tuple(sorted({int(g) for g in live_groups}))
+                if not groups or groups[0] < 0 or groups[-1] >= R:
+                    raise ValueError(
+                        f"live_groups must be a non-empty subset of "
+                        f"[0, {R}), got {live_groups}")
+            U = len(groups)
+            queries = jnp.atleast_2d(queries)
+            page = min(page, self.n_ids)
+            k = min(k, page)
+            page_loc = min(page, self.docs_per_shard + self.seg_capacity
+                           + sum(s.width for s in self.segments))
 
-        # round-robin over the LIVE replica groups: the batch splits along
-        # the replica axis, so pad it to U row-blocks and place block j in
-        # live column groups[j]; down columns receive zero rows.  All pad
-        # and dead-column rows are dropped again below, before the final
-        # rescore, and can never reach a caller.
-        n_q = queries.shape[0]
-        B = -(-n_q // U)                    # rows per live group
-        q = jnp.asarray(queries, jnp.float32)
-        pad_real = U * B - n_q
-        if pad_real:
-            q = jnp.concatenate(
-                [q, jnp.zeros((pad_real, q.shape[1]), jnp.float32)])
-        if U < R:
-            src = np.full(R * B, U * B, np.int64)       # OOB -> zero row
-            for j, c in enumerate(groups):
-                src[c * B:(c + 1) * B] = np.arange(j * B, (j + 1) * B)
-            q = jnp.concatenate(
-                [q, jnp.zeros((1, q.shape[1]), jnp.float32)])[jnp.asarray(src)]
-        q = normalize(q)
-        qcodes = self.encoder.encode(q)
-        mask = expand_mask(feature_mask(q, trim=trim, best=best),
-                           qcodes.shape[-1])
-        if profile is not None:
-            jax.block_until_ready((q, qcodes, mask))
-            t_now = time.monotonic()
-            profile.child("encode", t_now - t_prof,
-                          n_queries=int(n_q), groups=U)
-            t_prof = t_now
+            # round-robin over the LIVE replica groups: the batch splits
+            # along the replica axis, so pad it to U row-blocks and place
+            # block j in live column groups[j]; down columns receive zero
+            # rows.  All pad and dead-column rows are dropped again below,
+            # before the final rescore, and can never reach a caller.
+            n_q = queries.shape[0]
+            B = -(-n_q // U)                    # rows per live group
+            q = jnp.asarray(queries, jnp.float32)
+            pad_real = U * B - n_q
+            if pad_real:
+                q = jnp.concatenate(
+                    [q, jnp.zeros((pad_real, q.shape[1]), jnp.float32)])
+            if U < R:
+                src = np.full(R * B, U * B, np.int64)       # OOB -> zero row
+                for j, c in enumerate(groups):
+                    src[c * B:(c + 1) * B] = np.arange(j * B, (j + 1) * B)
+                zero = jnp.zeros((1, q.shape[1]), jnp.float32)
+                q = jnp.concatenate([q, zero])[jnp.asarray(src)]
+            q = normalize(q)
+            qcodes = self.encoder.encode(q)
+            mask = expand_mask(feature_mask(q, trim=trim, best=best),
+                               qcodes.shape[-1])
+            if profile is not None:
+                jax.block_until_ready((q, qcodes, mask))
+                t_now = time.monotonic()
+                profile.child("encode", t_now - t_prof,
+                              n_queries=int(n_q), groups=U)
+                t_prof = t_now
 
         if max_postings == "auto":
             max_postings = max(1, self.max_df)
@@ -1109,7 +1111,7 @@ class ShardedVectorIndex:
         # table (mixing quantized-cosine and idf-sum scales inside one
         # top_k would be meaningless); other engines pass no quant leaves
         quant = engine == "fused_int8"
-        with watch_region(
+        with annotation("repro.search.query_phase"), watch_region(
                 "search.query_phase",
                 sig=(tuple(q.shape), engine, weighting, int(page_loc),
                      int(L), int(k) if merge == "stream" else 0, merge,
@@ -1177,7 +1179,8 @@ class ShardedVectorIndex:
                            tombstones=int(self.active_tombstones),
                            candidates=int(np.isin(
                                appended, ag[ag >= 0]).sum()))
-        return _merge_phase(self, gids, scores, q, k=k, profile=profile)
+        with annotation("repro.search.merge"):
+            return _merge_phase(self, gids, scores, q, k=k, profile=profile)
 
 
 @partial(jax.jit, static_argnames=("mesh", "encoder", "index_best"))
@@ -1320,6 +1323,7 @@ def _merge_phase(sidx, gids, scores, q, *, k, profile=None):
 
 
 @partial(jax.jit, static_argnames=("k",))
+@jax.named_scope("merge_select")
 def _merge_select(vectors, gids, scores, *, k):
     top_s, pos = jax.lax.top_k(scores, k)
     top_ids = jnp.take_along_axis(gids, pos, axis=1)
@@ -1330,6 +1334,7 @@ def _merge_select(vectors, gids, scores, *, k):
 
 
 @partial(jax.jit, static_argnames=("k", "n_docs"))
+@jax.named_scope("merge_select")
 def _merge_select_seg(vectors, seg_parts, gids, scores, *, k, n_docs):
     """Merge select over base + appended generations.
 
@@ -1364,6 +1369,7 @@ def _merge_select_seg(vectors, seg_parts, gids, scores, *, k, n_docs):
 
 
 @jax.jit
+@jax.named_scope("rescore")
 def _rescore(cvec, q, top_ids):
     """exact_scores' canonical (Q, k, n) einsum over pre-fetched hits;
     unfillable (id -1) slots stay -inf instead of a junk-row cosine."""
@@ -1455,15 +1461,19 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
         if quant:
             w = None    # token-free engine: no df psum, no idf weights
         elif weighting == "idf":
-            df = df_lookup(postings, qcodes)
-            for i, (_, _, _, _, spd, spc) in enumerate(segs):
-                # sealed generations answer df off their mini posting
-                # lists: integer-equal to the dense code_df count, O(log G)
-                df = df + df_lookup(Postings(spd, spc, widths[i]), qcodes)
-            if G:
-                df = df + code_df(scod, qcodes)
-            df = jax.lax.psum(df, DATA_AXIS)        # global df, integer-exact
-            w = idf_weights(df, n_ids)
+            with jax.named_scope("df_lookup"):
+                df = df_lookup(postings, qcodes)
+                for i, (_, _, _, _, spd, spc) in enumerate(segs):
+                    # sealed generations answer df off their mini posting
+                    # lists: integer-equal to the dense code_df count,
+                    # O(log G)
+                    df = df + df_lookup(Postings(spd, spc, widths[i]),
+                                        qcodes)
+                if G:
+                    df = df + code_df(scod, qcodes)
+            with jax.named_scope("idf_psum"):
+                df = jax.lax.psum(df, DATA_AXIS)    # global df, exact
+                w = idf_weights(df, n_ids)
         elif weighting == "count":
             w = jnp.ones(qcodes.shape, jnp.float32)
         else:
@@ -1502,86 +1512,94 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
             s_seg = raw * ssc[None, :] + qsum * szp[None, :]
             return jnp.where(sl[None, :], s_seg, -jnp.inf)
 
-        if engine in FUSED_ENGINES:
-            # fused selection: the kernel streams the base and returns its
-            # top min(page_loc, dp) directly -- a superset of every base
-            # candidate the composed top-k could select -- then ONE top-k
-            # merges it with the (small) generation scores in the same
-            # concat-index space [base | sealed... | active] the composed
-            # path uses.  Stable top-k order matches the composed concat
-            # (base entries keep ascending-id tie order and precede
-            # generation entries), so `cand` is identical wherever scores
-            # are finite; -inf slots differ only in unspecified ids, which
-            # the live mask turns into (id=-1, -inf) either way.
-            from repro.kernels.fused_phase1 import ops as fp_ops
+        with jax.named_scope("phase1"):
+            if engine in FUSED_ENGINES:
+                # fused selection: the kernel streams the base and returns
+                # its top min(page_loc, dp) directly -- a superset of every
+                # base candidate the composed top-k could select -- then ONE
+                # top-k merges it with the (small) generation scores in the
+                # same concat-index space [base | sealed... | active] the
+                # composed path uses.  Stable top-k order matches the
+                # composed concat (base entries keep ascending-id tie order
+                # and precede generation entries), so `cand` is identical
+                # wherever scores are finite; -inf slots differ only in
+                # unspecified ids, which the live mask turns into (id=-1,
+                # -inf) either way.
+                from repro.kernels.fused_phase1 import ops as fp_ops
 
-            p_base = min(page_loc, dp)
-            if quant:
-                qsum = jnp.sum(q, axis=-1, keepdims=True)
-                s_b, ids_b = fp_ops.fused_phase1_quant(
-                    bq8, bsc, bzp, q, page=p_base, live=lv)
+                p_base = min(page_loc, dp)
+                if quant:
+                    qsum = jnp.sum(q, axis=-1, keepdims=True)
+                    s_b, ids_b = fp_ops.fused_phase1_quant(
+                        bq8, bsc, bzp, q, page=p_base, live=lv)
+                else:
+                    s_b, ids_b = fp_ops.fused_phase1(
+                        codes, qcodes, w, page=p_base, live=lv)
+                parts_s, parts_i = [s_b], [ids_b]
+                gen_off = dp
+                gen_sc = ([seg_scores_quant(seg_quants[i], segs[i][3])
+                           for i in range(n_sealed)] if quant else
+                          [seg_scores_fused(segs[i][1], segs[i][3])
+                           for i in range(n_sealed)])
+                for i, sc_i in enumerate(gen_sc):
+                    parts_s.append(sc_i)
+                    parts_i.append(gen_off + jax.lax.broadcasted_iota(
+                        jnp.int32, sc_i.shape, 1))
+                    gen_off += widths[i]
+                if G:
+                    sc_a = (seg_scores_quant((aq8, asc, azp), sliv) if quant
+                            else seg_scores_fused(scod, sliv))
+                    parts_s.append(sc_a)
+                    parts_i.append(gen_off + jax.lax.broadcasted_iota(
+                        jnp.int32, sc_a.shape, 1))
+                if len(parts_s) == 1:
+                    cand = ids_b                        # p_base == page_loc
+                else:
+                    cat_s = jnp.concatenate(parts_s, axis=1)
+                    cat_i = jnp.concatenate(parts_i, axis=1)
+                    _, pos = jax.lax.top_k(cat_s, page_loc)
+                    cand = jnp.take_along_axis(cat_i, pos, axis=1)
             else:
-                s_b, ids_b = fp_ops.fused_phase1(
-                    codes, qcodes, w, page=p_base, live=lv)
-            parts_s, parts_i = [s_b], [ids_b]
-            gen_off = dp
-            gen_sc = ([seg_scores_quant(seg_quants[i], segs[i][3])
-                       for i in range(n_sealed)] if quant else
-                      [seg_scores_fused(segs[i][1], segs[i][3])
-                       for i in range(n_sealed)])
-            for i, sc_i in enumerate(gen_sc):
-                parts_s.append(sc_i)
-                parts_i.append(gen_off + jax.lax.broadcasted_iota(
-                    jnp.int32, sc_i.shape, 1))
-                gen_off += widths[i]
-            if G:
-                sc_a = (seg_scores_quant((aq8, asc, azp), sliv) if quant
-                        else seg_scores_fused(scod, sliv))
-                parts_s.append(sc_a)
-                parts_i.append(gen_off + jax.lax.broadcasted_iota(
-                    jnp.int32, sc_a.shape, 1))
-            if len(parts_s) == 1:
-                cand = ids_b                        # p_base == page_loc
-            else:
-                cat_s = jnp.concatenate(parts_s, axis=1)
-                cat_i = jnp.concatenate(parts_i, axis=1)
-                _, pos = jax.lax.top_k(cat_s, page_loc)
-                cand = jnp.take_along_axis(cat_i, pos, axis=1)
-        else:
-            s1 = phase1_engine_scores(codes, postings, qcodes, w, engine,
-                                      max_postings, max_abs_bucket)
-            s1 = jnp.where(lv[None, :], s1, -jnp.inf)  # pads/tombstones out
-            parts = [s1]
-            parts += [seg_scores(sc_, sl_) for _, sc_, _, sl_, _, _ in segs]
-            if G:
-                parts.append(seg_scores(scod, sliv))
-            s1 = (parts[0] if len(parts) == 1
-                  else jnp.concatenate(parts, axis=1))
-            _, cand = jax.lax.top_k(s1, page_loc)   # (Q, page_loc)
+                s1 = phase1_engine_scores(codes, postings, qcodes, w,
+                                          engine, max_postings,
+                                          max_abs_bucket)
+                # pads/tombstones out
+                s1 = jnp.where(lv[None, :], s1, -jnp.inf)
+                parts = [s1]
+                parts += [seg_scores(sc_, sl_)
+                          for _, sc_, _, sl_, _, _ in segs]
+                if G:
+                    parts.append(seg_scores(scod, sliv))
+                s1 = (parts[0] if len(parts) == 1
+                      else jnp.concatenate(parts, axis=1))
+                _, cand = jax.lax.top_k(s1, page_loc)   # (Q, page_loc)
 
-        if segs or G:
-            vparts = [vec] + [t[0] for t in segs]
-            lparts = [lv] + [t[3] for t in segs]
-            gparts = ([off + jnp.arange(dp, dtype=jnp.int32)]
-                      + [t[2] for t in segs])
-            if G:
-                vparts.append(svec)
-                lparts.append(sliv)
-                gparts.append(sgid)
-            vec_all = jnp.concatenate(vparts, axis=0)
-            live_all = jnp.concatenate(lparts)
-            gid_all = jnp.concatenate(gparts)
-        else:
-            vec_all, live_all = vec, lv
-        cvec = vec_all[cand]                        # (Q, page_loc, n)
-        s2 = jnp.einsum("qpn,qn->qp", cvec, q,
-                        preferred_element_type=jnp.float32, precision=EXACT)
-        s2 = jnp.where(live_all[cand], s2, -jnp.inf)
-        gid = (gid_all[cand] if (segs or G)
-               else (cand + off).astype(jnp.int32))
+        with jax.named_scope("shard_rescore"):
+            if segs or G:
+                vparts = [vec] + [t[0] for t in segs]
+                lparts = [lv] + [t[3] for t in segs]
+                gparts = ([off + jnp.arange(dp, dtype=jnp.int32)]
+                          + [t[2] for t in segs])
+                if G:
+                    vparts.append(svec)
+                    lparts.append(sliv)
+                    gparts.append(sgid)
+                vec_all = jnp.concatenate(vparts, axis=0)
+                live_all = jnp.concatenate(lparts)
+                gid_all = jnp.concatenate(gparts)
+            else:
+                vec_all, live_all = vec, lv
+            cvec = vec_all[cand]                        # (Q, page_loc, n)
+            s2 = jnp.einsum("qpn,qn->qp", cvec, q,
+                            preferred_element_type=jnp.float32,
+                            precision=EXACT)
+            s2 = jnp.where(live_all[cand], s2, -jnp.inf)
+            gid = (gid_all[cand] if (segs or G)
+                   else (cand + off).astype(jnp.int32))
         if merge == "gather":
             return gid, s2
-        return _stream_merge_local(gid, s2, n_shards, k)
+        with jax.named_scope("merge_select"):
+            return _stream_merge_local(gid, s2, n_shards, k)
 
     rep = REPLICA_AXIS in mesh.axis_names
     qaxis = REPLICA_AXIS if rep else None

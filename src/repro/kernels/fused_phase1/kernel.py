@@ -158,14 +158,16 @@ def _ordered(scores, ids):
     return -neg, ids
 
 
-def _call(kernel, doc_inputs, lane_inputs, q_inputs, Q, d, page, block_q,
-          block_d, interpret):
+def _call(kernel, name, doc_inputs, lane_inputs, q_inputs, Q, d, page,
+          block_q, block_d, interpret):
     """Shared pallas_call plumbing: query-tile inputs replicate over the
     doc grid axis, doc-tile inputs over the query axis, lane-dense (1, d)
     per-doc rows tile along lanes, and both outputs revisit the same
     (BLOCK_Q, page) block for every doc tile.  The last doc tile may run
     past ``d``; the kernel masks those rows, so the tables are never
-    padded (a padded copy of a table costs its size again)."""
+    padded (a padded copy of a table costs its size again).  ``name``
+    names the kernel's op in HLO and in a profiler trace, whatever calls
+    it."""
     grid = (Q // block_q, pl.cdiv(d, block_d))
     q_specs = [pl.BlockSpec((block_q, x.shape[-1]), lambda i, j: (i, 0))
                for x in q_inputs]
@@ -182,6 +184,7 @@ def _call(kernel, doc_inputs, lane_inputs, q_inputs, Q, d, page, block_q,
         out_shape=[jax.ShapeDtypeStruct((Q, page), jnp.float32),
                    jax.ShapeDtypeStruct((Q, page), jnp.int32)],
         interpret=interpret,
+        name=name,
     )(*q_inputs, *doc_inputs, *lane_inputs)
     return _ordered(s, i)
 
@@ -206,7 +209,8 @@ def fused_phase1_pallas(
     d, _ = doc_codes.shape
     Q = qcodes.shape[0]
     assert Q % block_q == 0, (Q, block_q)
-    return _call(_fused_kernel, [doc_codes], [_lane_row(live, jnp.int32)],
+    return _call(_fused_kernel, "fused_phase1_pallas", [doc_codes],
+                 [_lane_row(live, jnp.int32)],
                  [qcodes.astype(jnp.int32), col_weights], Q, d, page,
                  block_q, block_d, interpret)
 
@@ -231,5 +235,6 @@ def fused_phase1_quant_pallas(
     assert Q % block_q == 0, (Q, block_q)
     lanes = [_lane_row(scale, jnp.float32), _lane_row(zero, jnp.float32),
              _lane_row(live, jnp.int32)]
-    return _call(_fused_quant_kernel, [qcodes8], lanes, [queries, qsum], Q,
-                 d, page, block_q, block_d, interpret)
+    return _call(_fused_quant_kernel, "fused_phase1_quant_pallas",
+                 [qcodes8], lanes, [queries, qsum], Q, d, page, block_q,
+                 block_d, interpret)

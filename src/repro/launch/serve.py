@@ -67,7 +67,7 @@ green -> yellow -> green across the injected failure and that the
 transition ledger reconciles EXACTLY (one down event for the failed
 group, counters match one-for-one); ``--diagnostics-on-exit DIR``
 writes a one-call support-diagnostics bundle (stats + health + device
-byte tables + compile/cost tables + slow log + metrics history) at the
+byte tables + compile tables + slow log + metrics history) at the
 end of the run and automatically at the moment a failover or
 kill-and-recover fires.
 """
@@ -169,7 +169,7 @@ def main():
                          "exposition size")
     ap.add_argument("--diagnostics-on-exit", default=None, metavar="DIR",
                     help="write a one-call diagnostics bundle (stats, "
-                         "cluster health, device/cost tables, slow log, "
+                         "cluster health, device tables, slow log, "
                          "compile stats, metrics history) into DIR at the "
                          "end of the run -- and automatically at the moment "
                          "a --fail-shard failover or --kill-and-recover "

@@ -7,8 +7,9 @@ serving, failover, auto-compaction, durability) while remaining
 completely blind at runtime.  This package is the missing observability
 plane, threaded through every serving layer at the host-side seams only
 -- instrumentation records timestamps *around* jitted program dispatch,
-never inside it, so compiled programs and their bit-parity pins are
-untouched.
+and inside a program only ``jax.named_scope`` names reach the device:
+scopes are metadata only, so compiled programs and their bit-parity pins
+are untouched.
 
 Each piece against its Elasticsearch analogue:
 
@@ -23,8 +24,10 @@ Each piece against its Elasticsearch analogue:
   follows a query submit -> queue wait -> batch formation -> device
   dispatch, with spill / failover-resubmit / health-transition events
   attached where they happened; ring-buffer retention, dump-on-demand,
-  optional ``jax.profiler.TraceAnnotation`` hooks so host spans line up
-  with captured device profiles.
+  and ``annotation``/``annotating``: ``jax.profiler.TraceAnnotation``
+  spans around every phase of a dispatch, so host spans line up with
+  captured device profiles (the span and scope names are listed in
+  ``docs/OBSERVABILITY.md``).
 * :mod:`repro.obs.stats` -- ``GET _stats`` / ``_cat``: one snapshot
   schema per layer (``BatchedSearchEngine.stats()`` =
   ``_cat/thread_pool`` for one replica group,
@@ -60,17 +63,12 @@ ES mapping):
   registry + a JSONL snapshot history ring
   (``serve.py --metrics-file``).
 
-v3 adds the *device* side -- what the programs and arrays actually cost:
+v3 adds the *device* side -- what the arrays and the cluster hold:
 
 * :mod:`repro.obs.device` -- exact index-resident byte accounting per
   shard/segment/quant-table leaf, per section and per device, reconciled
   against ``jax.live_arrays()`` (ES ``_nodes/stats`` store bytes +
   ``_cat/segments``).
-* :mod:`repro.obs.cost` -- XLA's static cost model captured at compile
-  time (FLOPs / bytes accessed / temp bytes per compiled program),
-  attributed to the same :func:`watch_region` stack the compile watch
-  uses; joined with measured phase latencies into a live roofline and a
-  serve-time check of the fused kernel's byte claim.
 * ``cluster_health()`` / ``node_stats()`` in :mod:`repro.obs.stats` --
   ES ``_cluster/health`` (green/yellow/red reconciled exactly against
   the HealthMap transition ledger) and ``_nodes/stats``.
@@ -80,8 +78,6 @@ v3 adds the *device* side -- what the programs and arrays actually cost:
 """
 
 from .compile_watch import CompileWatch, active_watch, watch_region
-from .cost import (CostTable, ensure_cost_capture, kernel_byte_ratio,
-                   missing_cost_regions, roofline, verify_kernel_claim)
 from .device import (device_bytes, format_device_line,
                      resident_leaf_entries)
 from .diagnostics import (BUNDLE_SECTIONS, diagnostics_bundle,
@@ -96,11 +92,11 @@ from .stats import (cluster_health, cluster_stats, engine_stats,
                     format_health_line, format_segments_line,
                     format_stats_line, index_stats, node_stats,
                     store_stats)
-from .tracing import NULL_TRACE, Span, Trace, Tracer, annotation
+from .tracing import NULL_TRACE, Span, Trace, Tracer, annotating, annotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
-    "Span", "Trace", "Tracer", "NULL_TRACE", "annotation",
+    "Span", "Trace", "Tracer", "NULL_TRACE", "annotation", "annotating",
     "index_stats", "engine_stats", "cluster_stats", "store_stats",
     "cluster_health", "node_stats",
     "format_stats_line", "format_segments_line", "format_health_line",
@@ -109,7 +105,5 @@ __all__ = [
     "CompileWatch", "active_watch", "watch_region",
     "MetricsExporter", "prometheus_text", "health_gauges", "device_gauges",
     "device_bytes", "format_device_line", "resident_leaf_entries",
-    "CostTable", "ensure_cost_capture", "missing_cost_regions",
-    "roofline", "kernel_byte_ratio", "verify_kernel_claim",
     "BUNDLE_SECTIONS", "diagnostics_bundle", "write_diagnostics",
 ]

@@ -109,7 +109,6 @@ class CompileWatch:
     """
 
     def __init__(self, metrics=None, enabled: bool = True):
-        from repro.obs.cost import CostTable, ensure_cost_capture
         from repro.obs.metrics import default_registry
 
         self.enabled = enabled
@@ -120,12 +119,8 @@ class CompileWatch:
         self._steady_events: List[dict] = []
         self._total = 0
         self._steady_total = 0
-        # static FLOPs/bytes per compiled program, attributed through the
-        # same region stack as compile counting (repro.obs.cost)
-        self.costs = CostTable()
         if enabled:
             _ensure_listener()
-            ensure_cost_capture()
 
     # -------------------------------------------------------------- regions
     def region(self, name: str, sig=()):

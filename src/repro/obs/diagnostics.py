@@ -21,8 +21,6 @@ section                   contents (ES analogue)
 ``device``                per-group :func:`~repro.obs.device.
                           device_bytes` leaf tables (``_cat/segments``
                           bytes view)
-``cost``                  static FLOPs/bytes rows per watch region
-                          (:class:`~repro.obs.cost.CostTable`)
 ``compile``               compile-watch counters + steady-state events
 ``slowlog``               the slow-log ring, NOT cleared (dumping
                           diagnostics must not eat the evidence)
@@ -48,9 +46,8 @@ from typing import Optional
 
 __all__ = ["diagnostics_bundle", "write_diagnostics", "BUNDLE_SECTIONS"]
 
-BUNDLE_SECTIONS = ("meta", "stats", "health", "nodes", "device", "cost",
-                   "compile", "slowlog", "traces", "metrics",
-                   "metrics_history")
+BUNDLE_SECTIONS = ("meta", "stats", "health", "nodes", "device", "compile",
+                   "slowlog", "traces", "metrics", "metrics_history")
 
 
 def _jsonable(obj):
@@ -115,7 +112,6 @@ def diagnostics_bundle(engine, *, exporter=None,
         "health": health,
         "nodes": node_stats(engine),
         "device": device,
-        "cost": watch.costs.stats() if watch is not None else None,
         "compile": watch.stats() if watch is not None else None,
         "slowlog": (None if slowlog is None
                     else {"entries": slowlog.dump(clear=False),

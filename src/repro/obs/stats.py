@@ -70,13 +70,8 @@ def _compile_stats(watch) -> dict:
     list -- stats lines want the totals; ``watch.stats()`` has the rest.
     """
     s = watch.stats()
-    out = {k: s[k] for k in ("compiles_total", "compiles_steady_state",
-                             "steady", "signatures", "by_function")}
-    # the static-cost rollup (FLOPs/bytes per region) rides the same
-    # section; raw rows stay on watch.costs for the diagnostics bundle
-    cost = watch.costs.stats()
-    out["cost"] = {"n_rows": cost["n_rows"], "by_region": cost["by_region"]}
-    return out
+    return {k: s[k] for k in ("compiles_total", "compiles_steady_state",
+                              "steady", "signatures", "by_function")}
 
 
 def index_stats(index) -> dict:

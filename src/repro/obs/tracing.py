@@ -11,13 +11,16 @@ timings), the tasks API (where is my request right now), and the profile
 API (per-phase breakdown); here it is one object per request.
 
 Discipline (same as :mod:`repro.obs.metrics`): spans carry host-side
-timestamps taken *around* jitted program dispatch, never inside it --
-tracing can never perturb a compiled program or its bit-parity.  To line
-host spans up with what the device actually did, ``annotation(name)``
-optionally opens a ``jax.profiler.TraceAnnotation`` around the dispatch
-(enabled via ``Tracer(annotate=True)``): when a ``jax.profiler`` device
-trace is being captured, the host span names then appear on the
-profiler's timeline next to the device ops they enclose.
+timestamps taken *around* jitted program dispatch.  Inside a program
+only ``jax.named_scope`` names reach the device, and scopes are metadata
+only: they change no compiled op and no bit-parity pin.  To line host
+spans up with what the device actually did, ``annotation(name)`` opens a
+``jax.profiler.TraceAnnotation`` while :func:`annotating` is on in the
+calling context (the engine's worker turns it on when its
+``Tracer(annotate=True)``): when a ``jax.profiler`` device trace is being
+captured, the host span names then appear on the profiler's timeline
+next to the device ops they enclose, and the device ops carry their
+scope path.
 
 Retention is a bounded ring buffer (``capacity`` most recent finished
 traces, ES ``tasks``-style dump-on-demand via :meth:`Tracer.dump`), and
@@ -30,20 +33,39 @@ sites never branch.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import itertools
 import threading
 import time
 from collections import deque
 from typing import List, Optional
 
-__all__ = ["Span", "Trace", "Tracer", "NULL_TRACE", "annotation"]
+__all__ = ["Span", "Trace", "Tracer", "NULL_TRACE", "annotation",
+           "annotating"]
+
+# whether annotation() spans open in this context: set by annotating()
+_ANNOTATE = contextvars.ContextVar("repro_obs_annotate", default=False)
 
 
-def annotation(name: str, enabled: bool = True):
-    """Context manager: a ``jax.profiler.TraceAnnotation`` around a
-    program dispatch when enabled and jax is importable, else a no-op.
-    Host-side only -- it never changes what is compiled or executed."""
-    if not enabled:
+@contextlib.contextmanager
+def annotating(on: bool = True):
+    """Turn :func:`annotation` spans on (or off) for the code this wraps,
+    in the calling thread's context only -- so a search called from an
+    annotating engine's worker annotates its phases, with no argument
+    passed down."""
+    token = _ANNOTATE.set(on)
+    try:
+        yield
+    finally:
+        _ANNOTATE.reset(token)
+
+
+def annotation(name: str):
+    """Context manager: a ``jax.profiler.TraceAnnotation`` named ``name``
+    while :func:`annotating` is on in this context, else a
+    ``nullcontext``.  Host-side only -- it never changes what is compiled
+    or executed."""
+    if not _ANNOTATE.get():
         return contextlib.nullcontext()
     try:
         from jax.profiler import TraceAnnotation
@@ -193,9 +215,10 @@ class Tracer:
     surfacing one full trace per batch on average); admission is a
     deterministic counter (every ``round(1/sample)``-th start), so runs
     reproduce.  ``capacity`` bounds retained finished traces (oldest
-    evicted).  ``annotate=True`` additionally opens
-    ``jax.profiler.TraceAnnotation`` spans around program dispatch so
-    host spans line up with captured device profiles.
+    evicted).  ``annotate=True`` additionally makes an engine that holds
+    this tracer open ``jax.profiler.TraceAnnotation`` spans around each
+    phase of a dispatch (:func:`annotating`), so host spans line up with
+    captured device profiles.
     """
 
     def __init__(self, capacity: int = 256, sample: float = 1.0 / 16,

@@ -64,7 +64,9 @@ queue wait, batch formation, device dispatch -- to any
 :class:`~repro.obs.tracing.Trace` riding the submit.  All timestamps
 are host-side, taken around the jitted program dispatch; the batch
 deadline and the queue-wait spans share ONE clock read per dequeue, so
-the batcher's accounting and the trace always agree on a wait.
+the batcher's accounting and the trace always agree on a wait.  With a
+``Tracer(annotate=True)`` every phase of the worker's loop (wait, batch
+formation, dispatch and its readback, resolve) is also a profiler span.
 ``stats()`` is the ES ``_cat/thread_pool`` view of this batcher.
 """
 
@@ -84,7 +86,7 @@ from repro.obs.compile_watch import active_watch
 from repro.obs.metrics import default_registry
 from repro.obs.profile import ProfileNode
 from repro.obs.slowlog import start_request_trace
-from repro.obs.tracing import annotation
+from repro.obs.tracing import annotating, annotation
 
 __all__ = ["BatchedSearchEngine"]
 
@@ -340,53 +342,72 @@ class BatchedSearchEngine:
 
     # --------------------------------------------------------------- worker
     def _run(self):
-        while True:
-            with self._lock:
-                # the batch deadline anchors to the OLDEST queued request's
-                # enqueue time (a request waits at most max_wait_s before
-                # dispatch), and each wake-up reads the clock ONCE -- the
-                # old loop re-read time.monotonic() on every predicate
-                # evaluation and anchored the deadline to worker wake-up,
-                # so a request arriving into an idle worker could dispatch
-                # immediately (deadline already stale) and the measured
-                # wait was unknowable
-                while len(self._queue) < self.batch_size and not self._stop:
-                    now = time.monotonic()
-                    if self._queue:
-                        deadline = self._queue[0][2] + self.max_wait_s
-                        if now >= deadline:
-                            break
-                        self._lock.wait(timeout=deadline - now)
-                    else:
-                        self._lock.wait(timeout=self.max_wait_s)
-                if self._stop and not self._queue:
-                    return
-                t_deq = time.monotonic()
-                batch = self._queue[: self.batch_size]
-                del self._queue[: len(batch)]
-                # snapshot under the lock: a hot swap after this point
-                # applies to the NEXT batch, this one finishes on `index`.
-                # _serving publishes the snapshot so a concurrent
-                # donate-ingest knows these buffers are being read
-                index = self.index
-                self._serving = index if batch else None
-                self._inflight = len(batch)
-            if not batch:
-                continue
-            # one t_deq for the whole batch: the queue-wait each metric
-            # and trace span reports is (t_deq - enqueue), same clock read;
-            # one lock acquisition for the whole batch's waits
-            self._h_wait.observe_many(
-                [t_deq - it[2] for it in batch])
-            self._h_occupancy.observe(len(batch) / self.batch_size)
-            # a failing search must not kill the worker: every queued and
-            # in-flight future would strand (resolve only by caller
-            # timeout) -- fail this batch's futures, serve the next batch
+        # with an annotating tracer every phase of the worker's loop (and
+        # the index's search phases under it) opens a profiler span, each
+        # at the clock read its metrics and trace spans use
+        with annotating(self.tracer is not None and self.tracer.annotate):
+            while self._serve_next():
+                pass
+
+    def _next_batch(self):
+        """Block until a batch can form -> (batch, index, t_deq), or None
+        once closed and drained."""
+        with self._lock:
+            # the batch deadline anchors to the OLDEST queued request's
+            # enqueue time (a request waits at most max_wait_s before
+            # dispatch), and each wake-up reads the clock ONCE -- the
+            # old loop re-read time.monotonic() on every predicate
+            # evaluation and anchored the deadline to worker wake-up,
+            # so a request arriving into an idle worker could dispatch
+            # immediately (deadline already stale) and the measured
+            # wait was unknowable
+            while len(self._queue) < self.batch_size and not self._stop:
+                now = time.monotonic()
+                if self._queue:
+                    deadline = self._queue[0][2] + self.max_wait_s
+                    if now >= deadline:
+                        break
+                    self._lock.wait(timeout=deadline - now)
+                else:
+                    self._lock.wait(timeout=self.max_wait_s)
+            if self._stop and not self._queue:
+                return None
+            t_deq = time.monotonic()
+            batch = self._queue[: self.batch_size]
+            del self._queue[: len(batch)]
+            # snapshot under the lock: a hot swap after this point
+            # applies to the NEXT batch, this one finishes on `index`.
+            # _serving publishes the snapshot so a concurrent
+            # donate-ingest knows these buffers are being read
+            index = self.index
+            self._serving = index if batch else None
+            self._inflight = len(batch)
+        return batch, index, t_deq
+
+    def _serve_next(self) -> bool:
+        """Serve one batch; False once closed and drained."""
+        with annotation("repro.engine.wait"):
+            got = self._next_batch()
+        if got is None:
+            return False
+        batch, index, t_deq = got
+        if not batch:
+            return True
+        # a failing search must not kill the worker: every queued and
+        # in-flight future would strand (resolve only by caller
+        # timeout) -- fail this batch's futures, serve the next batch
+        try:
+            error = prof = ids = scores = None
+            t_dispatch = t_deq    # overwritten once the batch is built
             try:
-                error = None
-                prof = None
-                t_dispatch = t_deq    # overwritten once the batch is built
-                try:
+                with annotation("repro.engine.batch_form"):
+                    # one t_deq for the whole batch: the queue-wait each
+                    # metric and trace span reports is (t_deq - enqueue),
+                    # same clock read; one lock acquisition for the whole
+                    # batch's waits
+                    self._h_wait.observe_many(
+                        [t_deq - it[2] for it in batch])
+                    self._h_occupancy.observe(len(batch) / self.batch_size)
                     qs = np.stack([it[0] for it in batch])
                     pad = self.batch_size - qs.shape[0]
                     if pad:
@@ -408,74 +429,75 @@ class BatchedSearchEngine:
                         if _accepts_profile(index):
                             kwargs["profile"] = prof
                     t_dispatch = time.monotonic()
-                    with annotation("repro.engine.dispatch",
-                                    self.tracer is not None
-                                    and self.tracer.annotate):
-                        with self.compile_watch.region(
-                                "engine.dispatch",
-                                sig=(qs.shape, str(qs.dtype), self.engine,
-                                     self.k, self.page,
-                                     self.merge or "gather")):
-                            ids, scores = index.search(
-                                jnp.asarray(qs), k=self.k, page=self.page,
-                                trim=self.trim, engine=self.engine,
-                                **kwargs,
-                            )
+                with annotation("repro.engine.dispatch"):
+                    with self.compile_watch.region(
+                            "engine.dispatch",
+                            sig=(qs.shape, str(qs.dtype), self.engine,
+                                 self.k, self.page,
+                                 self.merge or "gather")):
+                        ids, scores = index.search(
+                            jnp.asarray(qs), k=self.k, page=self.page,
+                            trim=self.trim, engine=self.engine,
+                            **kwargs,
+                        )
+                        with annotation("repro.engine.readback"):
                             ids, scores = np.asarray(ids), np.asarray(scores)
-                except Exception as exc:  # noqa: BLE001 - fwd to futures
-                    t_done = time.monotonic()
-                    error = exc
-                else:
-                    t_done = time.monotonic()
-                    if prof is not None:
-                        prof.duration_s = t_done - t_dispatch
-                self._h_dispatch.observe(t_done - t_dispatch)
-                # record spans BEFORE resolving futures: resolving fires
-                # the submitter's done-callback, which finishes the trace
-                # -- and a slow log serializes the span list at finish
-                # time (the tracer ring holds live traces, so it never
-                # noticed ordering; the slow log does)
-                for _, _, t_enq, tr, _ in batch:
-                    if not tr:          # NULL_TRACE: skip the kwargs builds
-                        continue
-                    tr.span("queue_wait", t0=t_enq, t1=t_deq,
-                            group=self.group)
-                    tr.span("batch_form", t0=t_deq, t1=t_dispatch,
-                            batch_size=len(batch), group=self.group)
-                    tr.span("dispatch", t0=t_dispatch, t1=t_done,
-                            group=self.group, batch_size=len(batch),
-                            **({} if error is None
-                               else {"error": repr(error)}))
-                if error is not None:
-                    for _, fut, _, _, _ in batch:
-                        if not fut.done():
-                            fut.set_exception(error)
-                    self._c_failed.inc(len(batch))
-                else:
-                    for i, (_, fut, t_enq, _, want) in enumerate(batch):
-                        if fut.done():      # caller may have cancelled
-                            continue
-                        if want:
-                            # per-request root over the shared dispatch
-                            # subtree; all phase bounds are SHARED clock
-                            # reads, so queue_wait + batch_form + dispatch
-                            # tile the total exactly
-                            root = ProfileNode(
-                                "query", t_done - t_enq,
-                                engine=self.engine, k=self.k,
-                                page=self.page,
-                                **({} if self.group is None
-                                   else {"group": self.group}))
-                            root.child("queue_wait", t_deq - t_enq)
-                            root.child("batch_form", t_dispatch - t_deq,
-                                       batch_size=len(batch))
-                            root.children.append(prof)
-                            fut.set_result(
-                                (ids[i], scores[i], root.to_dict()))
-                        else:
-                            fut.set_result((ids[i], scores[i]))
-                    self._c_completed.inc(len(batch))
-                    self._c_kernel_path.inc()   # one dispatch on `engine`
-            finally:
-                self._inflight = 0
-                self._serving = None
+            except Exception as exc:  # noqa: BLE001 - fwd to futures
+                t_done = time.monotonic()
+                error = exc
+            else:
+                t_done = time.monotonic()
+                if prof is not None:
+                    prof.duration_s = t_done - t_dispatch
+            with annotation("repro.engine.resolve"):
+                self._resolve(batch, error, ids, scores, prof, t_deq,
+                              t_dispatch, t_done)
+        finally:
+            self._inflight = 0
+            self._serving = None
+        return True
+
+    def _resolve(self, batch, error, ids, scores, prof, t_deq, t_dispatch,
+                 t_done) -> None:
+        """Record the dispatch and resolve the batch's futures."""
+        self._h_dispatch.observe(t_done - t_dispatch)
+        # record spans BEFORE resolving futures: resolving fires the
+        # submitter's done-callback, which finishes the trace -- and a
+        # slow log serializes the span list at finish time (the tracer
+        # ring holds live traces, so it never noticed ordering; the slow
+        # log does)
+        for _, _, t_enq, tr, _ in batch:
+            if not tr:          # NULL_TRACE: skip the kwargs builds
+                continue
+            tr.span("queue_wait", t0=t_enq, t1=t_deq, group=self.group)
+            tr.span("batch_form", t0=t_deq, t1=t_dispatch,
+                    batch_size=len(batch), group=self.group)
+            tr.span("dispatch", t0=t_dispatch, t1=t_done,
+                    group=self.group, batch_size=len(batch),
+                    **({} if error is None else {"error": repr(error)}))
+        if error is not None:
+            for _, fut, _, _, _ in batch:
+                if not fut.done():
+                    fut.set_exception(error)
+            self._c_failed.inc(len(batch))
+            return
+        for i, (_, fut, t_enq, _, want) in enumerate(batch):
+            if fut.done():      # caller may have cancelled
+                continue
+            if want:
+                # per-request root over the shared dispatch subtree; all
+                # phase bounds are SHARED clock reads, so queue_wait +
+                # batch_form + dispatch tile the total exactly
+                root = ProfileNode(
+                    "query", t_done - t_enq, engine=self.engine, k=self.k,
+                    page=self.page,
+                    **({} if self.group is None else {"group": self.group}))
+                root.child("queue_wait", t_deq - t_enq)
+                root.child("batch_form", t_dispatch - t_deq,
+                           batch_size=len(batch))
+                root.children.append(prof)
+                fut.set_result((ids[i], scores[i], root.to_dict()))
+            else:
+                fut.set_result((ids[i], scores[i]))
+        self._c_completed.inc(len(batch))
+        self._c_kernel_path.inc()   # one dispatch on `engine`

@@ -1,6 +1,6 @@
-"""Observability v3 (repro/obs): device byte accounting, compile-time
-cost attribution, ES _cluster/health, diagnostics bundles, exposition
-hardening, the host-seam lint, and the perf-regression gate.
+"""Observability v3 (repro/obs): device byte accounting, ES
+_cluster/health, diagnostics bundles, exposition hardening, the host-seam
+lint, and the perf-regression gate.
 
 The pinned invariants:
 
@@ -10,11 +10,6 @@ The pinned invariants:
   once; totals SHRINK after ``compact()``; on a replicated mesh the
   per-device attribution exceeds the logical total by exactly the
   replication factor;
-* **no unattributed serving compiles** -- every region the compile
-  watch saw compile has a cost-analysis row (FLOPs / bytes accessed /
-  peak temp) captured at compile time, and the fused kernel's live
-  HBM-byte ratio vs the composed pipeline stays under the committed
-  ``BENCH_kernel_scale`` claim;
 * **health reconciles** -- ``cluster_health()`` walks green -> yellow
   -> red -> green exactly as failures are injected, and its transition
   ledger matches the health counters one-for-one;
@@ -44,9 +39,8 @@ from repro.launch.mesh import make_shard_mesh
 from repro.obs import (BUNDLE_SECTIONS, CompileWatch, MetricsRegistry,
                        cluster_health, device_bytes, device_gauges,
                        diagnostics_bundle, format_device_line,
-                       format_health_line, health_gauges, kernel_byte_ratio,
-                       missing_cost_regions, node_stats, prometheus_text,
-                       resident_leaf_entries, roofline, verify_kernel_claim,
+                       format_health_line, health_gauges, node_stats,
+                       prometheus_text, resident_leaf_entries,
                        write_diagnostics)
 from repro.serve.engine import BatchedSearchEngine
 
@@ -176,47 +170,6 @@ def _run_subprocess(script: str) -> None:
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, cwd=_REPO)
     assert "OK" in out.stdout, out.stdout + out.stderr
-
-
-# ------------------------------------------------------- cost attribution
-def test_cost_rows_cover_every_compiled_region(queries):
-    """Fresh shapes force real compiles; afterwards every region the
-    watch counted a compile for must hold a cost-analysis row -- no
-    unattributed serving compiles."""
-    rng = np.random.default_rng(4)
-    idx = ShardedVectorIndex.build_sharded(
-        rng.normal(size=(52, 12)).astype(np.float32), make_shard_mesh(1),
-        seal_threshold=64)
-    reg = MetricsRegistry()
-    watch = CompileWatch(metrics=reg)
-    q = queries[:, :12].astype(np.float32)
-    for engine in ("codes", "fused"):
-        eng = BatchedSearchEngine(idx, batch_size=4, k=5, page=52,
-                                  trim=None, engine=engine, metrics=reg,
-                                  compile_watch=watch)
-        try:
-            for v in q:
-                eng.search(v, timeout=60)
-        finally:
-            eng.close()
-    assert watch.compiles_total > 0
-    assert missing_cost_regions(watch) == []
-    stats = watch.costs.stats()
-    assert stats["n_rows"] > 0
-    for region, agg in stats["by_region"].items():
-        assert agg["compiles"] >= 1, region
-        assert agg["bytes_accessed"] >= 0, region
-    # the live fused kernel must move fewer phase-1 bytes than the
-    # composed pipeline, within the committed claim's slack
-    ratio = kernel_byte_ratio(watch)
-    assert ratio is not None and 0 < ratio["ratio"] < 1.0, ratio
-    claim = verify_kernel_claim(
-        watch, os.path.join(_REPO, "artifacts", "BENCH_kernel_scale.json"))
-    assert claim["live"]["ratio"] < 1.0 and claim["claimed_ratio"], claim
-    # a measured phase latency joins into an achieved-GB/s roofline row
-    rows = roofline(watch, {"search.query_phase": 1e-3})
-    by_region = {r["region"]: r for r in rows}
-    assert by_region["search.query_phase"]["achieved_gbps"] > 0
 
 
 # ----------------------------------------------------------- cluster health

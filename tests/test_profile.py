@@ -143,9 +143,8 @@ def test_full_instrumentation_bit_parity_all_engines(sidx, queries):
     """THE acceptance pin: every engine, segments + tombstones live,
     metrics + tracer + slow log + compile watch + profile trees ON --
     and the v3 plane polled between requests (device byte accounting +
-    node stats + cost capture) -- results bit-identical to a bare
-    engine, and every region the watch saw compile has a cost row."""
-    from repro.obs import device_bytes, missing_cost_regions, node_stats
+    node stats) -- results bit-identical to a bare engine."""
+    from repro.obs import device_bytes, node_stats
 
     for engine in ALL_ENGINES:
         bare = BatchedSearchEngine(
@@ -165,8 +164,6 @@ def test_full_instrumentation_bit_parity_all_engines(sidx, queries):
                 assert np.array_equal(bi, ii), engine
                 assert np.array_equal(bs, iscore), engine
                 assert tree["children"], engine
-            # cost attribution: no serving compile left unattributed
-            assert missing_cost_regions(inst.compile_watch) == [], engine
         finally:
             bare.close()
             inst.close()
