@@ -8,6 +8,11 @@ it jits:
   a "posting list" for token ``(column j, bucket b)`` is then the contiguous
   range of the sorted order whose codes equal ``b``.  Finding it is a binary
   search, ``O(log j)``, exactly the paper's term-dictionary lookup.
+* **df table** -- a column holds only ``2 * max_abs_bucket + 1`` legal
+  codes, so every token's document frequency is fixed by the index alone:
+  :func:`build_df_table` answers the range lookup once per (column, code)
+  when the postings are built, and a query reads its tokens' df from that
+  table (:func:`table_df`).
 * **score** -- for every surviving query token we fetch its posting range and
   scatter-add the token weight into a dense score accumulator
   (``jax.ops.segment_sum`` = the hash-map accumulator of the paper), then
@@ -26,9 +31,16 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["Postings", "build_postings", "lookup", "idf_weights",
-           "score_postings", "code_df", "df_lookup"]
+           "score_postings", "code_df", "df_lookup", "DF_TABLE_MAX_BYTES",
+           "build_df_table", "table_df"]
+
+# Largest df table kept per shard.  Only an encoder with int16 or int32
+# codes can pass it (800 columns of int8 codes take at most 0.8 MiB); its
+# shards keep answering df with the binary searches of df_lookup.
+DF_TABLE_MAX_BYTES = 64 << 20
 
 
 class Postings(NamedTuple):
@@ -91,12 +103,62 @@ def df_lookup(postings: Postings, qcodes: jnp.ndarray) -> jnp.ndarray:
     :func:`lookup`'s range.  Integer-exact and therefore bit-identical to
     :func:`code_df` over the same code matrix (tombstones and padding carry
     the sentinel, which sorts past every legal range), but O(log d) per
-    token instead of O(d) -- the df path sealed append segments switch to
-    once they carry their own mini posting tables
-    (:class:`repro.dist.shard_index.Segment`).
+    token instead of O(d).  The search path reads df from the table
+    :func:`build_df_table` fills with this lookup, and calls it per query
+    only where the code range is too wide for a table (:func:`table_df`).
     """
     lo, hi = jax.vmap(lambda c: lookup(postings, c))(qcodes)
     return (hi - lo).astype(jnp.int32)
+
+
+def _table_codes(max_abs_bucket: int, sentinel: int) -> np.ndarray:
+    """The code each entry of a df table holds the df of, in entry order:
+    the ``2 * max_abs_bucket + 1`` legal codes, then the sentinel (unless it
+    is one of them)."""
+    return np.union1d(np.arange(-max_abs_bucket, max_abs_bucket + 1),
+                      [sentinel])
+
+
+def build_df_table(postings: Postings, max_abs_bucket: int,
+                   sentinel: int) -> jnp.ndarray:
+    """The document frequency of every code a column's rows can carry.
+
+    -> (C, W) int32: entry ``[c, i]`` is :func:`df_lookup`'s answer for
+    code ``_table_codes(max_abs_bucket, sentinel)[i]`` in column ``c`` --
+    the legal codes, then the sentinel (the padded and tombstoned rows) --
+    so a table read is integer-identical to the lookup.  Where (C, W)
+    int32 would pass :data:`DF_TABLE_MAX_BYTES` (int16 or int32 codes) the
+    table is empty, (C, 0), and :func:`table_df` keeps the lookup.
+    """
+    C = postings.post_codes.shape[0]
+    vals = _table_codes(max_abs_bucket, sentinel)
+    if C * vals.size * 4 > DF_TABLE_MAX_BYTES:
+        return jnp.zeros((C, 0), jnp.int32)
+    vals = jnp.asarray(vals, postings.post_codes.dtype)
+    return df_lookup(postings, jnp.broadcast_to(vals[:, None],
+                                                (vals.shape[0], C))).T
+
+
+def table_df(table: jnp.ndarray, postings: Postings, qcodes: jnp.ndarray,
+             max_abs_bucket: int, sentinel: int) -> jnp.ndarray:
+    """Per-token document frequency, (Q, C) int32, read from ``table``
+    (:func:`build_df_table` of ``postings``).
+
+    The read compares each query code with the W codes the table holds and
+    sums the one entry that matches: on a TPU a dense compare-and-select
+    over (W, Q, C) that the vector unit does in one pass, where a gather of
+    the Q * C entries is a slow serial op.  Equal to ``df_lookup(postings,
+    qcodes)`` for every code: a code the table holds no entry for matches
+    none and reads 0, and no row carries one (a row's code is a legal
+    bucket of the same encoder or the sentinel).  An empty table (code
+    range too wide) falls back to :func:`df_lookup` itself.
+    """
+    if not table.shape[-1]:
+        return df_lookup(postings, qcodes)
+    vals = jnp.asarray(_table_codes(max_abs_bucket, sentinel), jnp.int32)
+    hit = qcodes.astype(jnp.int32)[None] == vals[:, None, None]  # (W, Q, C)
+    return jnp.sum(jnp.where(hit, table.T[:, None, :], 0), axis=0,
+                   dtype=jnp.int32)
 
 
 def idf_weights(df: jnp.ndarray, n_docs: int) -> jnp.ndarray:

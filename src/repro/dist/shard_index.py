@@ -69,8 +69,8 @@ SPMD program; neither path loops over shards on the host.
   through :func:`repro.core.postings.code_df`.
 * Once the buffer reaches ``seal_threshold`` rows it SEALS into an
   immutable :class:`Segment` (a Lucene segment/generation): truncated to
-  its exact width, with its own mini posting table for O(log G) df
-  lookups, and a fresh active buffer opens.  Search scores base + N sealed
+  its exact width, with its own mini posting table and df table, and a
+  fresh active buffer opens.  Search scores base + N sealed
   generations + the active buffer under ONE jitted SPMD program with
   per-generation live masks -- candidate order is append order per shard,
   which keeps results bit-identical to the flat single-buffer path at
@@ -82,15 +82,16 @@ SPMD program; neither path loops over shards on the host.
   :meth:`compact` to a delete-pressure last resort.
 * :meth:`delete` marks docs dead: the per-doc ``live`` mask goes False,
   the doc's codes become the sentinel, and the affected shards' posting
-  lists are rebuilt in the same one-program SPMD argsort the build uses --
-  so document frequencies are EXACT under tombstones (idf-sensitive
-  engines score identically before and after :meth:`compact`), unlike
-  Lucene's lazy semantics where df transiently counts deleted docs.  The
-  ``live`` mask stays the source of truth for result eligibility.  Each
-  shard's tombstone count is tracked host-side (``shard_tombstones``);
-  ``tombstone_ratio`` is the worst per-shard dead fraction, the trigger
-  the cluster maintenance daemon (:mod:`repro.cluster.maintenance`)
-  watches for background auto-compaction.
+  lists and df tables are rebuilt in the same one-program SPMD argsort
+  the build uses -- so document frequencies are EXACT under tombstones
+  (idf-sensitive engines score identically before and after
+  :meth:`compact`), unlike Lucene's lazy semantics where df transiently
+  counts deleted docs.  The ``live`` mask stays the source of truth for
+  result eligibility.  Each shard's tombstone count is tracked host-side
+  (``shard_tombstones``); ``tombstone_ratio`` is the worst per-shard dead
+  fraction, the trigger the cluster maintenance daemon
+  (:mod:`repro.cluster.maintenance`) watches for background
+  auto-compaction.
 * :meth:`compact` folds segments and tombstones back into a clean base by
   re-running the on-device sharded build over the live doc table.  Global
   ids are stable across compaction: dead ids simply stop existing (their
@@ -111,8 +112,10 @@ BUILD/INGEST INVARIANTS (relied on throughout):
   result eligibility.  When fewer than ``k`` live docs exist, unfillable
   result slots report ``(id=-1, score=-inf)``.
 
-IDF query weighting stays *global*: document frequencies are summed across
-shards with a ``psum`` over ``data`` (integer-exact, identical in every
+IDF query weighting stays *global*: each shard reads its tokens' document
+frequencies from its df table (:func:`repro.core.postings.build_df_table`,
+built with the postings), and they are summed across shards with a ``psum``
+over ``data`` (integer-exact, identical in every
 replica group), so trimming/weighting decisions are independent of both
 the shard count and the replica count.  ``N`` is the global id-space size
 (``n_docs`` + docs ever appended), ES ``maxDoc`` style.
@@ -137,12 +140,12 @@ import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.encoding import Encoder, RoundingEncoder
-from repro.obs.compile_watch import watch_region
+from repro.obs.compile_watch import watch_metrics, watch_region
 from repro.obs.tracing import annotation
 from repro.core.filtering import (BestFilter, TrimFilter, expand_mask,
                                   feature_mask, index_best_codes)
-from repro.core.postings import (Postings, build_postings, code_df,
-                                 df_lookup, idf_weights)
+from repro.core.postings import (Postings, build_df_table, build_postings,
+                                 code_df, idf_weights, table_df)
 from repro.core.quantize import quantize_rows
 from repro.core.rerank import EXACT, normalize
 from repro.core.search import (_SENTINEL, FUSED_ENGINES, VectorIndex,
@@ -175,19 +178,19 @@ class Segment:
 
     Sealed off the active append buffer once it outgrows the direct-match
     threshold: rows are truncated to their exact round-robin width and the
-    segment gets its own mini posting table (the same one-program SPMD
-    argsort the base build uses), so its document frequencies come from
-    O(log G) posting-range lookups instead of an O(G * C) dense count.
+    segment gets its own mini posting table and df table (the same
+    one-program SPMD build the base uses), so its document frequencies come
+    from one table read instead of an O(G * C) dense count.
     Phase-1 *scores* stay the direct bucket-equality match -- the identity
     every engine lowers to -- which is what keeps segmented search
     bit-identical to the flat append path at every (k, page).
 
     Segments are immutable in the Lucene sense: the only mutations are
     tombstoning through :meth:`ShardedVectorIndex.delete` (live -> False,
-    sentinel codes, mini postings rebuilt so df stays exact) and wholesale
-    replacement by :meth:`ShardedVectorIndex.merge_segments`.  ``n_rows``
-    and ``tombstones`` are host-side ints (never cross jit) feeding the
-    tiered merge policy's per-segment deleted-doc ratios.
+    sentinel codes, mini postings and df table rebuilt so df stays exact)
+    and wholesale replacement by :meth:`ShardedVectorIndex.merge_segments`.
+    ``n_rows`` and ``tombstones`` are host-side ints (never cross jit)
+    feeding the tiered merge policy's per-segment deleted-doc ratios.
     """
 
     vectors: jnp.ndarray     # (S, G, n) f32 unit rows; zero rows pad
@@ -196,12 +199,13 @@ class Segment:
     live: jnp.ndarray        # (S, G) bool
     post_docs: jnp.ndarray   # (S, C, G) int32 mini posting order
     post_codes: jnp.ndarray  # (S, C, G) sorted codes per shard
+    df_table: jnp.ndarray    # (S, C, W) int32 df by code (build_df_table)
     n_rows: int              # rows holding a doc (live or tombstoned)
     tombstones: int          # dead rows among n_rows
 
     def tree_flatten(self):
         children = (self.vectors, self.codes, self.gids, self.live,
-                    self.post_docs, self.post_codes)
+                    self.post_docs, self.post_codes, self.df_table)
         return children, (self.n_rows, self.tombstones)
 
     @classmethod
@@ -253,6 +257,8 @@ class ShardedVectorIndex:
     codes: jnp.ndarray        # (S, dp, C) int; sentinel rows pad/tombstone
     post_docs: jnp.ndarray    # (S, C, dp) int32 per-shard posting order
     post_codes: jnp.ndarray   # (S, C, dp) sorted codes per shard
+    df_table: jnp.ndarray     # (S, C, W) int32 per-shard df by code; W = 0
+    #                           where the code range is too wide to tabulate
     offsets: jnp.ndarray      # (S,) int32 global id of each shard's doc 0
     live: jnp.ndarray         # (S, dp) bool -- False = pad or tombstone
     seg_vectors: jnp.ndarray  # (S, G, n) f32 ACTIVE append-buffer vectors
@@ -273,7 +279,7 @@ class ShardedVectorIndex:
     # -- pytree plumbing (mesh/encoder/sizes are static metadata) ----------
     def tree_flatten(self):
         children = (self.vectors, self.codes, self.post_docs,
-                    self.post_codes, self.offsets, self.live,
+                    self.post_codes, self.df_table, self.offsets, self.live,
                     self.seg_vectors, self.seg_codes, self.seg_gids,
                     self.seg_live, self.segments)
         return children, (self.encoder, self.mesh, self.n_docs,
@@ -440,6 +446,7 @@ class ShardedVectorIndex:
         yield "codes", "base", self.codes
         yield "post_docs", "base", self.post_docs
         yield "post_codes", "base", self.post_codes
+        yield "df_table", "base", self.df_table
         yield "offsets", "base", self.offsets
         yield "live", "base", self.live
         yield "seg_vectors", "active", self.seg_vectors
@@ -448,7 +455,7 @@ class ShardedVectorIndex:
         yield "seg_live", "active", self.seg_live
         for i, seg in enumerate(self.segments):
             for nm in ("vectors", "codes", "gids", "live",
-                       "post_docs", "post_codes"):
+                       "post_docs", "post_codes", "df_table"):
                 yield f"segments[{i}].{nm}", "segments", getattr(seg, nm)
             q = seg.__dict__.get("_quant_cache")
             if q is not None:
@@ -486,6 +493,7 @@ class ShardedVectorIndex:
             codes=put(self.codes, _ROW),
             post_docs=put(self.post_docs, _ROW),
             post_codes=put(self.post_codes, _ROW),
+            df_table=put(self.df_table, _ROW),
             offsets=put(self.offsets, P(DATA_AXIS)),
             live=put(self.live, _VEC),
             seg_vectors=put(self.seg_vectors, _ROW),
@@ -496,15 +504,16 @@ class ShardedVectorIndex:
                 Segment(put(s.vectors, _ROW), put(s.codes, _ROW),
                         put(s.gids, _VEC), put(s.live, _VEC),
                         put(s.post_docs, _ROW), put(s.post_codes, _ROW),
-                        s.n_rows, s.tombstones)
+                        put(s.df_table, _ROW), s.n_rows, s.tombstones)
                 for s in self.segments),
         )
 
     # -------------------------------------------------------- introspection
     def token_df(self, queries) -> jnp.ndarray:
         """Global per-token document frequencies, (Q, C) int32 -- EXACTLY
-        what the query phase's idf weighting sees: per-shard base postings
-        lookup + segment code match, psum over ``data``.  With the eager
+        what the query phase's idf weighting sees: per-shard df table reads
+        (base and sealed generations) + the active buffer's code match,
+        psum over ``data``.  With the eager
         postings refresh in :meth:`delete` this counts live docs only, so
         it is invariant under :meth:`compact` -- the pin behind the
         "idf-sensitive engines score identically across compaction"
@@ -512,10 +521,12 @@ class ShardedVectorIndex:
         q = normalize(jnp.atleast_2d(jnp.asarray(queries, jnp.float32)))
         qcodes = self.encoder.encode(q)
         seg = self.seg_capacity > 0
-        sealed = tuple((s.post_docs, s.post_codes) for s in self.segments)
+        sealed = tuple((s.post_docs, s.post_codes, s.df_table)
+                       for s in self.segments)
         return _token_df_program(
-            self.post_docs, self.post_codes,
-            self.seg_codes if seg else None, sealed, qcodes, mesh=self.mesh)
+            self.post_docs, self.post_codes, self.df_table,
+            self.seg_codes if seg else None, sealed, qcodes, mesh=self.mesh,
+            max_abs_bucket=self.encoder.max_abs_bucket)
 
     # ----------------------------------------------------------------- build
     @classmethod
@@ -556,8 +567,9 @@ class ShardedVectorIndex:
     ) -> "ShardedVectorIndex":
         """Build the index ON the mesh: one compiled SPMD program runs
         normalize -> encode -> ``index_best`` masking -> ``build_postings``
-        per shard under ``shard_map`` -- no per-shard host loop, no host
-        round-trip (device-resident ``vectors`` are resharded in place).
+        -> ``build_df_table`` per shard under ``shard_map`` -- no per-shard
+        host loop, no host round-trip (device-resident ``vectors`` are
+        resharded in place).
 
         Bit-identical to ``VectorIndex.build(vectors, ...)`` followed by
         :meth:`from_index` (pinned by tests/test_build_parity.py): every
@@ -583,14 +595,16 @@ class ShardedVectorIndex:
 
         with watch_region("build.program",
                           sig=(int(ns), int(dp), int(n_feat))):
-            vecs, codes, pdocs, pcodes = _build_program(
+            vecs, codes, pdocs, pcodes, table = _build_program(
                 raw, lv, mesh=mesh, encoder=encoder, index_best=index_best)
+        _count_df_table(table)
 
         return cls(
             vectors=vecs,
             codes=codes,
             post_docs=pdocs,
             post_codes=pcodes,
+            df_table=table,
             offsets=_put(mesh, cls._offsets(ns, dp), P(DATA_AXIS)),
             live=lv,
             encoder=encoder,
@@ -640,7 +654,8 @@ class ShardedVectorIndex:
         # to the tail of every posting list, so padded docs are invisible to
         # range lookups
         with watch_region("build.postings", sig=tuple(codes.shape)):
-            pdocs, pcodes = _postings_program(codes, mesh=mesh)
+            pdocs, pcodes, table = _shard_postings(
+                codes, mesh, index.encoder.max_abs_bucket)
 
         offsets = cls._offsets(ns, dp)
         counts = np.clip(n - offsets, 0, dp)        # real rows per shard
@@ -650,6 +665,7 @@ class ShardedVectorIndex:
             codes=codes,
             post_docs=pdocs,
             post_codes=pcodes,
+            df_table=table,
             offsets=_put(mesh, offsets, P(DATA_AXIS)),
             live=_put(mesh, live, _VEC),
             encoder=index.encoder,
@@ -764,11 +780,12 @@ class ShardedVectorIndex:
         """Seal the active append buffer into an immutable :class:`Segment`.
 
         The buffer is truncated to its exact round-robin width, gets its
-        own mini posting table (the same one-program SPMD argsort the base
-        build and :meth:`delete` use), and joins ``segments``; the next
-        :meth:`add_documents` opens a fresh active buffer whose geometric
-        growth ladder restarts from empty.  A pure function of the op
-        history, so translog replay re-seals at identical boundaries.
+        own mini posting table and df table (the same one-program SPMD
+        build the base build and :meth:`delete` use), and joins
+        ``segments``; the next :meth:`add_documents` opens a fresh active
+        buffer whose geometric growth ladder restarts from empty.  A pure
+        function of the op history, so translog replay re-seals at
+        identical boundaries.
         """
         ns = self.n_shards
         n_act = self.n_active
@@ -780,8 +797,9 @@ class ShardedVectorIndex:
         sgid = _put(self.mesh, self.seg_gids[:, :w], _VEC)
         sliv = _put(self.mesh, self.seg_live[:, :w], _VEC)
         with watch_region("ingest.seal", sig=(int(w), ns)):
-            pdocs, pcodes = _postings_program(scod, mesh=self.mesh)
-        seg = Segment(svec, scod, sgid, sliv, pdocs, pcodes,
+            pdocs, pcodes, table = _shard_postings(
+                scod, self.mesh, self.encoder.max_abs_bucket)
+        seg = Segment(svec, scod, sgid, sliv, pdocs, pcodes, table,
                       n_rows=n_act, tombstones=self.active_tombstones)
         # the sealed generation inherits the active buffer's quant cache
         # as its own (same vector bits; the seal is a truncating slice, and
@@ -806,13 +824,14 @@ class ShardedVectorIndex:
         The doc's ``live`` flag goes False and its codes become the
         sentinel, so the ``codes``/``onehot`` engines skip it outright and
         the ``live`` mask blocks it from every result page.  Base posting
-        lists are REBUILT in the same one-program SPMD argsort the build
-        uses (the sentinel sorts every tombstone to the list tails), so
-        document frequencies are exact immediately -- idf weights, and
-        therefore idf-sensitive phase-1 scores, are identical before and
-        after :meth:`compact`.  That is stricter than Lucene (which lets
-        df count deleted docs until a merge) at the cost of one argsort
-        per delete batch -- a control-plane price, not a query-path one.
+        lists and df tables are REBUILT in the same one-program SPMD argsort
+        the build uses (the sentinel sorts every tombstone to the list
+        tails), so document frequencies are exact immediately -- idf
+        weights, and therefore idf-sensitive phase-1 scores, are identical
+        before and after :meth:`compact`.  That is stricter than Lucene
+        (which lets df count deleted docs until a merge) at the cost of one
+        argsort per delete batch -- a control-plane price, not a query-path
+        one.
         Deleting an already-dead or padded id is a no-op for that id (and
         does not count toward ``shard_tombstones``).
         """
@@ -835,9 +854,11 @@ class ShardedVectorIndex:
             new["codes"] = _put(self.mesh,
                                 self.codes.at[s, r].set(sentinel), _ROW)
             # exact-df postings refresh: one SPMD argsort over the updated
-            # codes drops the tombstones out of every posting list
-            pdocs, pcodes = _postings_program(new["codes"], mesh=self.mesh)
-            new["post_docs"], new["post_codes"] = pdocs, pcodes
+            # codes drops the tombstones out of every posting list and df
+            # table
+            new["post_docs"], new["post_codes"], new["df_table"] = \
+                _shard_postings(new["codes"], self.mesh,
+                                self.encoder.max_abs_bucket)
         app = ids[ids >= self.n_docs]
         if app.size:
             segs = list(self.segments)
@@ -854,11 +875,12 @@ class ShardedVectorIndex:
                               seg.codes.at[s, g].set(sentinel), _ROW)
                 live2 = _put(self.mesh, seg.live.at[s, g].set(False), _VEC)
                 # exact df under tombstones, per generation: rebuild the
-                # segment's mini posting table so the sentinel sorts its
-                # dead rows past every legal lookup range
-                pdocs, pcodes = _postings_program(codes2, mesh=self.mesh)
+                # segment's mini posting table and df table so its dead
+                # rows count only under the sentinel
+                pdocs, pcodes, table = _shard_postings(
+                    codes2, self.mesh, self.encoder.max_abs_bucket)
                 segs[i] = Segment(seg.vectors, codes2, seg.gids, live2,
-                                  pdocs, pcodes, seg.n_rows,
+                                  pdocs, pcodes, table, seg.n_rows,
                                   seg.tombstones + n_new)
                 if "_quant_cache" in seg.__dict__:
                     # same vectors leaf; dead rows are live-masked before
@@ -930,13 +952,13 @@ class ShardedVectorIndex:
         Content-preserving, not a rebuild: surviving rows keep their unit
         vectors, codes, and global ids verbatim; they are re-packed
         round-robin in id order and the merged segment gets a fresh mini
-        posting table.  Tombstones the run carried are RECLAIMED -- the
-        per-shard ``shard_tombstones`` counters drop by exactly the dead
-        rows merged away, so ``tombstone_ratio`` keeps meaning "deletes a
-        compact could still fold".  Search results are bit-identical
-        before and after for ``page >= n_ids``: removed rows were already
-        ``-inf`` everywhere, and surviving rows keep their relative id
-        order, so candidate tie-breaks cannot shift.
+        posting table and df table.  Tombstones the run carried are
+        RECLAIMED -- the per-shard ``shard_tombstones`` counters drop by
+        exactly the dead rows merged away, so ``tombstone_ratio`` keeps
+        meaning "deletes a compact could still fold".  Search results are
+        bit-identical before and after for ``page >= n_ids``: removed rows
+        were already ``-inf`` everywhere, and surviving rows keep their
+        relative id order, so candidate tie-breaks cannot shift.
 
         Assembly is host-side gathers + ONE ``device_put`` per leaf --
         never a scatter from replica-replicated leaves (GSPMD reassembles
@@ -1001,8 +1023,9 @@ class ShardedVectorIndex:
         dgid = _put(self.mesh, mg, _VEC)
         dliv = _put(self.mesh, ml, _VEC)
         with watch_region("merge.postings", sig=(int(w), ns)):
-            pdocs, pcodes = _postings_program(dcod, mesh=self.mesh)
-        merged = Segment(dvec, dcod, dgid, dliv, pdocs, pcodes,
+            pdocs, pcodes, table = _shard_postings(
+                dcod, self.mesh, self.encoder.max_abs_bucket)
+        merged = Segment(dvec, dcod, dgid, dliv, pdocs, pcodes, table,
                          n_rows=n_live, tombstones=0)
         return dataclasses.replace(
             self, segments=before + (merged,) + after,
@@ -1105,7 +1128,8 @@ class ShardedVectorIndex:
             else min(max_postings, self.docs_per_shard)
         seg = self.seg_capacity > 0
         sealed = tuple(
-            (s.vectors, s.codes, s.gids, s.live, s.post_docs, s.post_codes)
+            (s.vectors, s.codes, s.gids, s.live, s.post_docs, s.post_codes,
+             s.df_table)
             for s in self.segments)
         # fused_int8 scores every generation off its lazily derived int8
         # table (mixing quantized-cosine and idf-sum scales inside one
@@ -1118,7 +1142,7 @@ class ShardedVectorIndex:
                      len(self.segments), bool(seg))):
             gids, scores = _query_phase(
                 self.vectors, self.codes, self.post_docs, self.post_codes,
-                self.offsets, self.live,
+                self.df_table, self.offsets, self.live,
                 self.seg_vectors if seg else None,
                 self.seg_codes if seg else None,
                 self.seg_gids if seg else None,
@@ -1188,11 +1212,12 @@ def _build_program(raw, live, *, mesh, encoder, index_best):
     """THE on-device build: one SPMD program, whole pipeline per shard.
 
     Every stage is row-wise (normalize, encode, best-mask) or
-    column-independent over the local rows (the posting argsort), so each
-    shard's block produces bit-identical results to the same rows inside a
-    single-device build -- which is exactly the parity the property suite
-    pins.  ``live=False`` rows (pads, carried tombstones) become zero
-    vectors with sentinel codes, sorting to the tail of every posting list.
+    column-independent over the local rows (the posting argsort, the df
+    table), so each shard's block produces bit-identical results to the
+    same rows inside a single-device build -- which is exactly the parity
+    the property suite pins.  ``live=False`` rows (pads, carried
+    tombstones) become zero vectors with sentinel codes, sorting to the
+    tail of every posting list.
     """
     from .shmap import shard_map
 
@@ -1207,10 +1232,12 @@ def _build_program(raw, live, *, mesh, encoder, index_best):
         codes = jnp.where(lv[:, None], codes,
                           jnp.asarray(sentinel, codes.dtype))
         p = build_postings(codes)
-        return v[None], codes[None], p.post_docs[None], p.post_codes[None]
+        table = build_df_table(p, encoder.max_abs_bucket, sentinel)
+        return (v[None], codes[None], p.post_docs[None], p.post_codes[None],
+                table[None])
 
     fn = shard_map(local, mesh=mesh, in_specs=(_ROW, _VEC),
-                   out_specs=(_ROW, _ROW, _ROW, _ROW), check=False)
+                   out_specs=(_ROW,) * 5, check=False)
     return fn(raw, live)
 
 
@@ -1259,19 +1286,41 @@ def _quantize_program(vectors, *, mesh):
     return fn(vectors)
 
 
-@partial(jax.jit, static_argnames=("mesh",))
-def _postings_program(codes, *, mesh):
-    """Per-shard posting-list build in one SPMD program (from_index path:
-    codes already exist, only the argsort runs per shard)."""
+@partial(jax.jit, static_argnames=("mesh", "max_abs_bucket"))
+def _postings_program(codes, *, mesh, max_abs_bucket):
+    """Per-shard posting lists and df table in one SPMD program (codes
+    already exist, only the argsort and the table's lookups run per
+    shard)."""
     from .shmap import shard_map
+
+    sentinel = int(_SENTINEL[codes.dtype])
 
     def local(c):
         p = build_postings(c[0])
-        return p.post_docs[None], p.post_codes[None]
+        table = build_df_table(p, max_abs_bucket, sentinel)
+        return p.post_docs[None], p.post_codes[None], table[None]
 
     fn = shard_map(local, mesh=mesh, in_specs=(_ROW,),
-                   out_specs=(_ROW, _ROW), check=False)
+                   out_specs=(_ROW, _ROW, _ROW), check=False)
     return fn(codes)
+
+
+def _shard_postings(codes, mesh: Mesh, max_abs_bucket: int):
+    """(post_docs, post_codes, df_table) of every shard of ``codes``
+    (S, W, C): :func:`_postings_program`, with the table counted."""
+    out = _postings_program(codes, mesh=mesh, max_abs_bucket=max_abs_bucket)
+    _count_df_table(out[2])
+    return out
+
+
+def _count_df_table(table) -> None:
+    """Count one (re)build of an index's df tables -- build, delete's
+    exact-df refresh, seal, merge, restore -- in ``index.df_table.builds``
+    of the registry this thread's watch regions report to.  Searches only
+    read tables, so they never count; an empty table (code range too wide)
+    is not a table."""
+    if table.shape[-1]:
+        watch_metrics().counter("index.df_table.builds").inc()
 
 
 def _merge_phase(sidx, gids, scores, q, *, k, profile=None):
@@ -1381,8 +1430,8 @@ def _rescore(cvec, q, top_ids):
 @partial(jax.jit, static_argnames=("mesh", "max_abs_bucket", "page_loc",
                                    "engine", "weighting", "max_postings",
                                    "k", "merge"))
-def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
-                 seg_vectors, seg_codes, seg_gids, seg_live, sealed,
+def _query_phase(vectors, codes, post_docs, post_codes, df_table, offsets,
+                 live, seg_vectors, seg_codes, seg_gids, seg_live, sealed,
                  base_quant, act_quant, sealed_quant,
                  q, qcodes, mask, n_ids, *, mesh, max_abs_bucket, page_loc,
                  engine, weighting, max_postings, k, merge):
@@ -1397,18 +1446,25 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
     batch additionally splits along ``replica`` (Q/R rows per group) and
     reassembles in the out-spec.
 
+    Under ``weighting="idf"`` (scope ``df_lookup``) each shard reads its
+    tokens' df from its ``df_table`` in one pass (``table_df``, a
+    compare-and-select over the codes the table holds, integer-identical to
+    the posting-range lookup the table was built with); a shard whose code
+    range is too wide for a table (width 0) runs that lookup per token
+    instead.
+
     Appended docs live in generations: ``sealed`` is a tuple of
-    ``(vectors, codes, gids, live, post_docs, post_codes)`` leaf-tuples --
-    one per sealed :class:`Segment` -- and ``seg_*`` is the active append
-    buffer (``None`` when empty).  Every generation scores by direct
-    per-column bucket equality (the identity every engine lowers to, which
-    is what pins bit-parity with the flat path), but *df* comes from each
-    sealed segment's mini posting table (``df_lookup``, integer-exact and
-    equal to the dense count) while the active buffer still uses
-    ``code_df``.  Candidate order is base, then generations oldest-first,
-    then the active buffer -- per shard that is exactly append order, the
-    same tie-break order as the flat buffer, so ``top_k`` stability makes
-    the candidate pages match the pre-generational program bit for bit.
+    ``(vectors, codes, gids, live, post_docs, post_codes, df_table)``
+    leaf-tuples -- one per sealed :class:`Segment` -- and ``seg_*`` is the
+    active append buffer (``None`` when empty).  Every generation scores by
+    direct per-column bucket equality (the identity every engine lowers
+    to, which is what pins bit-parity with the flat path), but *df* comes
+    from each sealed segment's own df table (integer-exact and equal to
+    the dense count) while the active buffer still uses ``code_df``.
+    Candidate order is base, then generations oldest-first, then the
+    active buffer -- per shard that is exactly append order, the same
+    tie-break order as the flat buffer, so ``top_k`` stability makes the
+    candidate pages match the pre-generational program bit for bit.
 
     Takes leaves, not the index pytree, and the id-space size ``n_ids`` as
     a TRACED scalar: repeated ingest batches that stay within the segment
@@ -1435,15 +1491,17 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
     widths = tuple(t[0].shape[1] for t in sealed)
     quant = engine == "fused_int8"
 
+    sentinel = int(_SENTINEL[codes.dtype])
+
     def local(*args):
-        vec, codes, pdocs, pcodes, off, lv = args[:6]
-        rest = args[6:]
+        vec, codes, pdocs, pcodes, dft, off, lv = args[:7]
+        rest = args[7:]
         if G:
             svec, scod, sgid, sliv = (x[0] for x in rest[:4])
             rest = rest[4:]
-        segs = [tuple(x[0] for x in rest[i * 6:(i + 1) * 6])
+        segs = [tuple(x[0] for x in rest[i * 7:(i + 1) * 7])
                 for i in range(n_sealed)]
-        rest = rest[n_sealed * 6:]
+        rest = rest[n_sealed * 7:]
         if quant:
             bq8, bsc, bzp = (x[0] for x in rest[:3])
             rest = rest[3:]
@@ -1462,13 +1520,13 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
             w = None    # token-free engine: no df psum, no idf weights
         elif weighting == "idf":
             with jax.named_scope("df_lookup"):
-                df = df_lookup(postings, qcodes)
-                for i, (_, _, _, _, spd, spc) in enumerate(segs):
-                    # sealed generations answer df off their mini posting
-                    # lists: integer-equal to the dense code_df count,
-                    # O(log G)
-                    df = df + df_lookup(Postings(spd, spc, widths[i]),
-                                        qcodes)
+                df = table_df(dft[0], postings, qcodes, max_abs_bucket,
+                              sentinel)
+                for i, (_, _, _, _, spd, spc, sdt) in enumerate(segs):
+                    # sealed generations read their own df tables:
+                    # integer-equal to the dense code_df count
+                    df = df + table_df(sdt, Postings(spd, spc, widths[i]),
+                                       qcodes, max_abs_bucket, sentinel)
                 if G:
                     df = df + code_df(scod, qcodes)
             with jax.named_scope("idf_psum"):
@@ -1567,7 +1625,7 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
                 s1 = jnp.where(lv[None, :], s1, -jnp.inf)
                 parts = [s1]
                 parts += [seg_scores(sc_, sl_)
-                          for _, sc_, _, sl_, _, _ in segs]
+                          for _, sc_, _, sl_, _, _, _ in segs]
                 if G:
                     parts.append(seg_scores(scod, sliv))
                 s1 = (parts[0] if len(parts) == 1
@@ -1603,14 +1661,14 @@ def _query_phase(vectors, codes, post_docs, post_codes, offsets, live,
 
     rep = REPLICA_AXIS in mesh.axis_names
     qaxis = REPLICA_AXIS if rep else None
-    args = [vectors, codes, post_docs, post_codes, offsets, live]
-    specs = [_ROW, _ROW, _ROW, _ROW, P(DATA_AXIS), _VEC]
+    args = [vectors, codes, post_docs, post_codes, df_table, offsets, live]
+    specs = [_ROW, _ROW, _ROW, _ROW, _ROW, P(DATA_AXIS), _VEC]
     if G:
         args += [seg_vectors, seg_codes, seg_gids, seg_live]
         specs += [_ROW, _ROW, _VEC, _VEC]
-    for sv_, sc_, sg_, sl_, spd_, spc_ in sealed:
-        args += [sv_, sc_, sg_, sl_, spd_, spc_]
-        specs += [_ROW, _ROW, _VEC, _VEC, _ROW, _ROW]
+    for leaves in sealed:
+        args += list(leaves)
+        specs += [_ROW, _ROW, _VEC, _VEC, _ROW, _ROW, _ROW]
     if quant:
         args += list(base_quant)
         specs += [_ROW, _VEC, _VEC]
@@ -1700,42 +1758,37 @@ def _max_df_program(post_codes, *, mesh, sentinel):
     return fn(post_codes)
 
 
-@partial(jax.jit, static_argnames=("mesh",))
-def _token_df_program(post_docs, post_codes, seg_codes, sealed, qcodes, *,
-                      mesh):
+@partial(jax.jit, static_argnames=("mesh", "max_abs_bucket"))
+def _token_df_program(post_docs, post_codes, df_table, seg_codes, sealed,
+                      qcodes, *, mesh, max_abs_bucket):
     """Global per-token df, the query phase's idf input verbatim: per-shard
-    postings range lookup (base + each sealed generation's mini posting
+    df table read (``table_df``: base + each sealed generation's own
     table) plus the active buffer's code match, psum over ``data``.
-    ``sealed`` is a tuple of (post_docs, post_codes) pairs.  Queries are
-    replicated (df is identical in every replica group)."""
+    ``sealed`` is a tuple of (post_docs, post_codes, df_table) triples.
+    Queries are replicated (df is identical in every replica group)."""
     from .shmap import shard_map
 
-    dp = post_codes.shape[-1]
     G = seg_codes is not None
-    n_sealed = len(sealed)
-    widths = tuple(pc.shape[-1] for _, pc in sealed)
+    sentinel = int(_SENTINEL[post_codes.dtype])
 
     def local(*args):
-        pd, pc = args[0], args[1]
-        rest = args[2:]
+        *leaves, qc = args
         if G:
-            sc = rest[0][0]
-            rest = rest[1:]
-        seg_posts = [(rest[2 * i][0], rest[2 * i + 1][0])
-                     for i in range(n_sealed)]
-        qc = rest[2 * n_sealed]
-        df = df_lookup(Postings(pd[0], pc[0], dp), qc)
-        for i, (spd, spc) in enumerate(seg_posts):
-            df = df + df_lookup(Postings(spd, spc, widths[i]), qc)
+            *leaves, sc = leaves
+        df = 0
+        for i in range(0, len(leaves), 3):      # base, then sealed triples
+            pd, pc, dt = (x[0] for x in leaves[i:i + 3])
+            df = df + table_df(dt, Postings(pd, pc, pc.shape[-1]), qc,
+                               max_abs_bucket, sentinel)
         if G:
-            df = df + code_df(sc, qc)
+            df = df + code_df(sc[0], qc)
         return jax.lax.psum(df, DATA_AXIS)
 
-    args = [post_docs, post_codes] + ([seg_codes] if G else [])
-    specs = [_ROW, _ROW] + ([_ROW] if G else [])
-    for spd_, spc_ in sealed:
-        args += [spd_, spc_]
-        specs += [_ROW, _ROW]
+    args = [post_docs, post_codes, df_table]
+    for leaves in sealed:
+        args += list(leaves)
+    args += [seg_codes] if G else []
+    specs = [_ROW] * len(args)
     args += [qcodes]
     specs += [P(None, None)]
     fn = shard_map(local, mesh=mesh, in_specs=tuple(specs),

@@ -38,7 +38,7 @@ import contextlib
 import threading
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["CompileWatch", "active_watch", "watch_region"]
+__all__ = ["CompileWatch", "active_watch", "watch_metrics", "watch_region"]
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _UNATTRIBUTED = "<unattributed>"
@@ -224,12 +224,23 @@ def active_watch() -> CompileWatch:
     return _default
 
 
+def _thread_watch() -> CompileWatch:
+    """The watch active on this thread, else the process default."""
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1][0] if stack else active_watch()
+
+
 def watch_region(name: str, sig=()):
     """A region on whichever watch is already active on this thread
     (else the process default) -- how the index's inner jitted seams
     (``search.query_phase``, ``ingest.append``, ``merge.postings``)
     inherit the engine's watch without threading a reference through
     every call."""
-    stack = getattr(_TLS, "stack", None)
-    watch = stack[-1][0] if stack else active_watch()
-    return watch.region(name, sig)
+    return _thread_watch().region(name, sig)
+
+
+def watch_metrics():
+    """The metrics registry :func:`watch_region`'s regions report to on
+    this thread -- where the index counts its own events
+    (``index.df_table.builds``)."""
+    return _thread_watch().metrics

@@ -49,10 +49,10 @@ referenced blob unlinked under it.
   ingest/merge used: active rows by their append offset
   (``gid - n_docs - seg_base``), sealed-segment rows by gid rank,
   round-robin -- search parity at ``page >= n_ids`` holds on any mesh.
-* per-shard posting lists (base and per-segment mini tables) are rebuilt
-  with the same one-program SPMD argsort (``_postings_program``) the live
-  index uses, so they are bit-identical to the committed index's on the
-  same mesh shape.
+* per-shard posting lists and df tables (base and per-segment mini
+  tables) are rebuilt with the same one-program SPMD argsort
+  (``_postings_program``) the live index uses, so they are bit-identical
+  to the committed index's on the same mesh shape.
 
 ``shard_tombstones`` is exact on a same-shard-count restore; restoring to
 a different shard count redistributes the writer's TOTAL round-robin
@@ -81,7 +81,7 @@ from repro.core.encoding import (CombinedEncoder, Encoder, IntervalEncoder,
                                  RoundingEncoder)
 from repro.core.search import _SENTINEL
 from repro.dist.shard_index import (Segment, ShardedVectorIndex,
-                                    _postings_program, _put, _ROW, _VEC)
+                                    _put, _ROW, _shard_postings, _VEC)
 from repro.dist.sharding import DATA_AXIS
 
 __all__ = ["CommitPoint", "write_commit", "latest_commit", "restore",
@@ -413,9 +413,9 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
     rules ingest/merge used (active rows by append offset, sealed rows by
     gid rank, round-robin) and places each leaf with one ``device_put``
     (scatter-free -- see module docstring for the replica-mesh GSPMD
-    gotcha); postings (base + per-segment mini tables) are rebuilt by the
-    same SPMD argsort the live paths use.  On any shape, search results
-    match at ``page >= n_ids``.
+    gotcha); postings and df tables (base + per-segment mini tables) are
+    rebuilt by the same SPMD argsort the live paths use.  On any shape,
+    search results match at ``page >= n_ids``.
     """
     meta = commit.meta
     store_dir = commit.data_path
@@ -445,7 +445,8 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
     vectors = _put(mesh, vec.reshape(ns, dp, nf), _ROW)
     codes = _put(mesh, codes.reshape(ns, dp, C), _ROW)
     live = _put(mesh, live.reshape(ns, dp), _VEC)
-    pdocs, pcodes = _postings_program(codes, mesh=mesh)
+    pdocs, pcodes, table = _shard_postings(codes, mesh,
+                                           encoder.max_abs_bucket)
 
     # ----- active append buffer
     if files["active"] is not None and same_shards:
@@ -501,10 +502,10 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
             mg[s, g] = gids[order].astype(np.int32)
             ml[s, g] = part["live"].reshape(-1)[rows][order]
         dcod = _put(mesh, mc, _ROW)
-        spd, spc = _postings_program(dcod, mesh=mesh)
+        spd, spc, sdt = _shard_postings(dcod, mesh, encoder.max_abs_bucket)
         segments.append(Segment(
             _put(mesh, mv, _ROW), dcod, _put(mesh, mg, _VEC),
-            _put(mesh, ml, _VEC), spd, spc,
+            _put(mesh, ml, _VEC), spd, spc, sdt,
             n_rows=int(e["n_rows"]), tombstones=int(e["tombstones"])))
 
     stones = [int(t) for t in meta["shard_tombstones"]]
@@ -520,6 +521,7 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
         codes=codes,
         post_docs=pdocs,
         post_codes=pcodes,
+        df_table=table,
         offsets=_put(mesh, ShardedVectorIndex._offsets(ns, dp),
                      P(DATA_AXIS)),
         live=live,

@@ -31,7 +31,8 @@ from repro.launch.mesh import make_shard_mesh
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_LEAVES = ("vectors", "codes", "post_docs", "post_codes", "offsets", "live")
+_LEAVES = ("vectors", "codes", "post_docs", "post_codes", "df_table",
+           "offsets", "live")
 
 
 def _assert_same_index(ref, dev, ctx):

@@ -42,9 +42,11 @@ from repro.store import (NoCommitError, Store, Translog,
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_LEAVES = ("vectors", "codes", "post_docs", "post_codes", "offsets", "live",
-           "seg_vectors", "seg_codes", "seg_gids", "seg_live")
-_SEG_LEAVES = ("vectors", "codes", "gids", "live", "post_docs", "post_codes")
+_LEAVES = ("vectors", "codes", "post_docs", "post_codes", "df_table",
+           "offsets", "live", "seg_vectors", "seg_codes", "seg_gids",
+           "seg_live")
+_SEG_LEAVES = ("vectors", "codes", "gids", "live", "post_docs", "post_codes",
+               "df_table")
 _ENGINES = ("postings", "codes", "onehot")
 
 
@@ -715,8 +717,9 @@ mesh = make_shard_mesh(4)
 store = Store(store_dir)
 live = store.open_index(ShardedVectorIndex.build_sharded(V, mesh))
 
-LEAVES = ("vectors", "codes", "post_docs", "post_codes", "offsets", "live",
-          "seg_vectors", "seg_codes", "seg_gids", "seg_live")
+LEAVES = ("vectors", "codes", "post_docs", "post_codes", "df_table",
+          "offsets", "live", "seg_vectors", "seg_codes", "seg_gids",
+          "seg_live")
 
 def check(live, tag):
     rec, seq = recover(store_dir, make_shard_mesh(4))
