@@ -83,7 +83,7 @@ def run(shard_counts, n_docs=20000, n_features=64, n_queries=32,
     queries = V[rng.choice(n_docs, size=n_queries, replace=False)]
 
     def leaves(sidx):
-        return (sidx.vectors, sidx.codes, sidx.post_docs, sidx.post_codes,
+        return (sidx.vectors, sidx.codes, sidx.df_table,
                 sidx.seg_vectors, sidx.seg_codes)
 
     rows = []

@@ -10,9 +10,9 @@ it jits:
   search, ``O(log j)``, exactly the paper's term-dictionary lookup.
 * **df table** -- a column holds only ``2 * max_abs_bucket + 1`` legal
   codes, so every token's document frequency is fixed by the index alone:
-  :func:`build_df_table` answers the range lookup once per (column, code)
-  when the postings are built, and a query reads its tokens' df from that
-  table (:func:`table_df`).
+  :func:`build_df_table` counts each (column, code) straight off the code
+  matrix, no posting list needed, and a query reads its tokens' df from
+  that table (:func:`table_df`).
 * **score** -- for every surviving query token we fetch its posting range and
   scatter-add the token weight into a dense score accumulator
   (``jax.ops.segment_sum`` = the hash-map accumulator of the paper), then
@@ -104,8 +104,8 @@ def df_lookup(postings: Postings, qcodes: jnp.ndarray) -> jnp.ndarray:
     :func:`code_df` over the same code matrix (tombstones and padding carry
     the sentinel, which sorts past every legal range), but O(log d) per
     token instead of O(d).  The search path reads df from the table
-    :func:`build_df_table` fills with this lookup, and calls it per query
-    only where the code range is too wide for a table (:func:`table_df`).
+    :func:`build_df_table` counts, and calls this lookup per query only
+    where the code range is too wide for a table (:func:`table_df`).
     """
     lo, hi = jax.vmap(lambda c: lookup(postings, c))(qcodes)
     return (hi - lo).astype(jnp.int32)
@@ -119,30 +119,35 @@ def _table_codes(max_abs_bucket: int, sentinel: int) -> np.ndarray:
                       [sentinel])
 
 
-def build_df_table(postings: Postings, max_abs_bucket: int,
+def build_df_table(codes: jnp.ndarray, max_abs_bucket: int,
                    sentinel: int) -> jnp.ndarray:
     """The document frequency of every code a column's rows can carry.
 
-    -> (C, W) int32: entry ``[c, i]`` is :func:`df_lookup`'s answer for
-    code ``_table_codes(max_abs_bucket, sentinel)[i]`` in column ``c`` --
-    the legal codes, then the sentinel (the padded and tombstoned rows) --
-    so a table read is integer-identical to the lookup.  Where (C, W)
-    int32 would pass :data:`DF_TABLE_MAX_BYTES` (int16 or int32 codes) the
-    table is empty, (C, 0), and :func:`table_df` keeps the lookup.
+    codes: (d, C) -> (C, W) int32: entry ``[c, i]`` counts the rows whose
+    column ``c`` holds code ``_table_codes(max_abs_bucket, sentinel)[i]``
+    -- the legal codes, then the sentinel (the padded and tombstoned rows)
+    -- so a table read is integer-identical to :func:`df_lookup` over the
+    same codes' postings and to :func:`code_df`.  One compare-and-sum pass
+    over the codes per table entry; no posting list is read or built.
+    Where (C, W) int32 would pass :data:`DF_TABLE_MAX_BYTES` (int16 or
+    int32 codes) the table is empty, (C, 0), and :func:`table_df` keeps
+    the lookup.
     """
-    C = postings.post_codes.shape[0]
+    C = codes.shape[1]
     vals = _table_codes(max_abs_bucket, sentinel)
     if C * vals.size * 4 > DF_TABLE_MAX_BYTES:
         return jnp.zeros((C, 0), jnp.int32)
-    vals = jnp.asarray(vals, postings.post_codes.dtype)
-    return df_lookup(postings, jnp.broadcast_to(vals[:, None],
-                                                (vals.shape[0], C))).T
+    vals = jnp.asarray(vals, codes.dtype)
+    counts = jax.lax.map(
+        lambda v: jnp.sum(codes == v, axis=0, dtype=jnp.int32), vals)
+    return counts.T                                          # (C, W)
 
 
-def table_df(table: jnp.ndarray, postings: Postings, qcodes: jnp.ndarray,
-             max_abs_bucket: int, sentinel: int) -> jnp.ndarray:
+def table_df(table: jnp.ndarray, postings: Optional[Postings],
+             qcodes: jnp.ndarray, max_abs_bucket: int,
+             sentinel: int) -> jnp.ndarray:
     """Per-token document frequency, (Q, C) int32, read from ``table``
-    (:func:`build_df_table` of ``postings``).
+    (:func:`build_df_table` of the rows ``postings`` index).
 
     The read compares each query code with the W codes the table holds and
     sums the one entry that matches: on a TPU a dense compare-and-select
@@ -150,8 +155,9 @@ def table_df(table: jnp.ndarray, postings: Postings, qcodes: jnp.ndarray,
     the Q * C entries is a slow serial op.  Equal to ``df_lookup(postings,
     qcodes)`` for every code: a code the table holds no entry for matches
     none and reads 0, and no row carries one (a row's code is a legal
-    bucket of the same encoder or the sentinel).  An empty table (code
-    range too wide) falls back to :func:`df_lookup` itself.
+    bucket of the same encoder or the sentinel).  Only an empty table (code
+    range too wide) reads ``postings``, through :func:`df_lookup` itself;
+    with a table they may be ``None``.
     """
     if not table.shape[-1]:
         return df_lookup(postings, qcodes)

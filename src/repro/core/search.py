@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -52,7 +53,7 @@ from .quantize import QuantizedTable, quantize_table
 from .rerank import brute_force_topk, normalize, rerank_topk
 
 __all__ = ["VectorIndex", "SearchParams", "phase1_engine_scores",
-           "FUSED_ENGINES"]
+           "FUSED_ENGINES", "encode_query_rows"]
 
 # engines that fuse phase-1 scoring with candidate selection: they return
 # the candidate page directly instead of a dense (Q, d) score matrix, so
@@ -102,6 +103,18 @@ def phase1_engine_scores(
     if engine == "onehot":
         return score_onehot(codes, qcodes, col_weights, max_abs_bucket)
     raise ValueError(f"unknown engine {engine!r}")
+
+
+@partial(jax.jit, static_argnames=("encoder", "trim", "best"))
+def encode_query_rows(q, *, encoder: Encoder, trim: Optional[TrimFilter],
+                      best: Optional[BestFilter]):
+    """(Q, n) float32 queries -> (unit queries, codes (Q, C), code-column
+    mask (Q, C)), as one program: the search path then dispatches once
+    per batch, not once per op (the pairwise norm alone is dozens)."""
+    q = normalize(q)
+    qcodes = encoder.encode(q)
+    return q, qcodes, expand_mask(feature_mask(q, trim=trim, best=best),
+                                  qcodes.shape[-1])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,9 +196,9 @@ class VectorIndex:
         weighting: str,
     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """-> (queries_normalised (Q,n), qcodes (Q,C), col_weights (Q,C))."""
-        q = normalize(jnp.asarray(queries, jnp.float32))
-        qcodes = self.encoder.encode(q)
-        mask = expand_mask(feature_mask(q, trim=trim, best=best), qcodes.shape[-1])
+        q, qcodes, mask = encode_query_rows(
+            jnp.asarray(queries, jnp.float32), encoder=self.encoder,
+            trim=trim, best=best)
         if weighting == "idf":
             lo, hi = jax.vmap(lambda qc: lookup(self.postings, qc))(qcodes)
             w = idf_weights(hi - lo, self.postings.n_docs)

@@ -5,8 +5,8 @@ contiguous *doc-shards* along the mesh's ``data`` axis, one shard per
 device.  A query runs the ES distributed query/fetch protocol:
 
 1. **query phase** (per shard, under ``shard_map``): phase-1 scoring over
-   the local codes/postings, local ``top_k(page)``, exact-cosine scoring of
-   the local candidate page;
+   the local codes (or posting lists, for the ``postings`` engine), local
+   ``top_k(page)``, exact-cosine scoring of the local candidate page;
 2. **merge phase**: per-shard candidate pages reach the coordinating
    reduce (ids are globalised by the shard's doc-id offset) and a global
    ``top_k(k)`` over the exact cosines picks the final hits.
@@ -54,10 +54,21 @@ Two control-plane entry points sit on top of the replica tier:
 raw vectors are ``device_put`` straight onto the ``data`` axis and ONE
 jitted SPMD program runs the whole pipeline per shard under ``shard_map``
 -- normalize -> ``encoder.encode`` -> ``index_best`` sentinel masking ->
-``build_postings`` -- so index construction scales with the mesh exactly
-like search does.  :meth:`from_index` (partitioning an existing
-single-device index) likewise rebuilds the per-shard posting lists in one
-SPMD program; neither path loops over shards on the host.
+the df table counted off the codes -- so index construction scales with
+the mesh exactly like search does.  :meth:`from_index` (partitioning an
+existing single-device index) likewise counts the per-shard df tables in
+one SPMD program; neither path loops over shards on the host.
+
+**Posting lists on demand**: the base's per-shard posting lists
+(``post_docs``/``post_codes``, a (C, dp) int32 and a (C, dp) code table
+per shard -- five times the bytes of int8 codes) are a derived
+cache of the base codes, like the int8 quant tables.  They are sorted on
+first use only, by what reads them: the ``postings`` engine,
+:attr:`max_df` (``max_postings="auto"``), a df read where the code range
+is too wide for a table, or a caller reading the properties.  The
+``fused`` engines and every df read through a table never build them.
+Each build counts in ``index.postings.builds`` and runs in the host span
+``repro.index.postings``.
 
 **Incremental ingest** (the full Lucene segment story):
 
@@ -81,9 +92,10 @@ SPMD program; neither path loops over shards on the host.
   tier's ``TieredMergePolicy`` schedules off the query path, demoting full
   :meth:`compact` to a delete-pressure last resort.
 * :meth:`delete` marks docs dead: the per-doc ``live`` mask goes False,
-  the doc's codes become the sentinel, and the affected shards' posting
-  lists and df tables are rebuilt in the same one-program SPMD argsort
-  the build uses -- so document frequencies are EXACT under tombstones
+  the doc's codes become the sentinel, and the affected df tables are
+  counted again off the new codes (sealed segments also re-sort their
+  mini posting lists; base posting lists are dropped, to be sorted again
+  on demand) -- so document frequencies are EXACT under tombstones
   (idf-sensitive engines score identically before and after
   :meth:`compact`), unlike Lucene's lazy semantics where df transiently
   counts deleted docs.  The ``live`` mask stays the source of truth for
@@ -101,7 +113,8 @@ BUILD/INGEST INVARIANTS (relied on throughout):
 
 * *Sentinel-tail postings*: padded and tombstoned rows carry the
   never-matching sentinel code, which sorts to the tail of every posting
-  list -- range lookups cannot reach them, and a legal query code can
+  list and counts only under the df table's sentinel entry -- range
+  lookups and table reads cannot reach them, and a legal query code can
   never equal the sentinel.
 * *Unsharded final rescore*: reported scores always come from the
   canonical ``(Q, k, n)`` einsum with unsharded operands on the
@@ -114,7 +127,7 @@ BUILD/INGEST INVARIANTS (relied on throughout):
 
 IDF query weighting stays *global*: each shard reads its tokens' document
 frequencies from its df table (:func:`repro.core.postings.build_df_table`,
-built with the postings), and they are summed across shards with a ``psum``
+counted off the codes), and they are summed across shards with a ``psum``
 over ``data`` (integer-exact, identical in every
 replica group), so trimming/weighting decisions are independent of both
 the shard count and the replica count.  ``N`` is the global id-space size
@@ -142,14 +155,13 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.core.encoding import Encoder, RoundingEncoder
 from repro.obs.compile_watch import watch_metrics, watch_region
 from repro.obs.tracing import annotation
-from repro.core.filtering import (BestFilter, TrimFilter, expand_mask,
-                                  feature_mask, index_best_codes)
+from repro.core.filtering import BestFilter, TrimFilter, index_best_codes
 from repro.core.postings import (Postings, build_df_table, build_postings,
                                  code_df, idf_weights, table_df)
 from repro.core.quantize import quantize_rows
 from repro.core.rerank import EXACT, normalize
 from repro.core.search import (_SENTINEL, FUSED_ENGINES, VectorIndex,
-                               phase1_engine_scores)
+                               encode_query_rows, phase1_engine_scores)
 
 from .sharding import DATA_AXIS, REPLICA_AXIS
 
@@ -178,8 +190,8 @@ class Segment:
 
     Sealed off the active append buffer once it outgrows the direct-match
     threshold: rows are truncated to their exact round-robin width and the
-    segment gets its own mini posting table and df table (the same
-    one-program SPMD build the base uses), so its document frequencies come
+    segment gets its own mini posting table and df table (one-program SPMD
+    builds, as for the base's df table), so its document frequencies come
     from one table read instead of an O(G * C) dense count.
     Phase-1 *scores* stay the direct bucket-equality match -- the identity
     every engine lowers to -- which is what keeps segmented search
@@ -250,13 +262,13 @@ class ShardedVectorIndex:
     contiguous document range plus its local->global id ``offset``.  The
     ``seg_*`` leaves are the per-shard append segments of incremental
     ingest (width 0 for a freshly built index); ``live`` is the per-doc
-    eligibility mask (False = pad or tombstone).
+    eligibility mask (False = pad or tombstone).  The base posting lists
+    are not leaves: :attr:`post_docs` / :attr:`post_codes` sort them from
+    the codes on first read and cache them per instance.
     """
 
     vectors: jnp.ndarray      # (S, dp, n) f32, unit rows; zero rows pad
     codes: jnp.ndarray        # (S, dp, C) int; sentinel rows pad/tombstone
-    post_docs: jnp.ndarray    # (S, C, dp) int32 per-shard posting order
-    post_codes: jnp.ndarray   # (S, C, dp) sorted codes per shard
     df_table: jnp.ndarray     # (S, C, W) int32 per-shard df by code; W = 0
     #                           where the code range is too wide to tabulate
     offsets: jnp.ndarray      # (S,) int32 global id of each shard's doc 0
@@ -278,10 +290,9 @@ class ShardedVectorIndex:
 
     # -- pytree plumbing (mesh/encoder/sizes are static metadata) ----------
     def tree_flatten(self):
-        children = (self.vectors, self.codes, self.post_docs,
-                    self.post_codes, self.df_table, self.offsets, self.live,
-                    self.seg_vectors, self.seg_codes, self.seg_gids,
-                    self.seg_live, self.segments)
+        children = (self.vectors, self.codes, self.df_table, self.offsets,
+                    self.live, self.seg_vectors, self.seg_codes,
+                    self.seg_gids, self.seg_live, self.segments)
         return children, (self.encoder, self.mesh, self.n_docs,
                           self.index_best, self.n_appended,
                           self.shard_tombstones, self.seal_threshold,
@@ -386,9 +397,10 @@ class ShardedVectorIndex:
         """Longest live posting list over every (shard, column): the exact
         per-shard ``max_postings`` window -- sized from the shard's actual
         code distribution instead of the ``docs_per_shard`` worst case.
-        Tombstone-free by construction (:meth:`delete` rebuilds postings,
-        sentinels are excluded), cached per instance (every mutation
-        returns a new index, so the cache can never go stale)."""
+        Reads the base posting lists, so builds them if nothing has yet.
+        Tombstone-free by construction (tombstones carry the sentinel,
+        which is excluded), cached per instance (every mutation returns a
+        new index, so the cache can never go stale)."""
         cached = self.__dict__.get("_max_df_cache")
         if cached is None:
             cached = int(_max_df_program(
@@ -396,6 +408,44 @@ class ShardedVectorIndex:
                 sentinel=int(_SENTINEL[self.codes.dtype])))
             self.__dict__["_max_df_cache"] = cached
         return cached
+
+    # --------------------------------------------------- base posting lists
+    def _postings(self):
+        """(post_docs, post_codes), each (S, C, dp): the base's per-shard
+        posting lists, sorted from the codes on first use and cached per
+        instance.  Mutations that leave the base codes alone carry the
+        cache to the new index (:meth:`_carry`); the rest drop it."""
+        cached = self.__dict__.get("_postings_cache")
+        if cached is None:
+            with annotation("repro.index.postings"), watch_region(
+                    "build.postings", sig=tuple(self.codes.shape)):
+                cached = _postings_program(self.codes, mesh=self.mesh)
+            watch_metrics().counter("index.postings.builds").inc()
+            self.__dict__["_postings_cache"] = cached
+        return cached
+
+    @property
+    def post_docs(self) -> jnp.ndarray:
+        """(S, C, dp) int32 doc ids, sorted by code per column and shard
+        (built on first read)."""
+        return self._postings()[0]
+
+    @property
+    def post_codes(self) -> jnp.ndarray:
+        """(S, C, dp) the sorted codes themselves (built on first read)."""
+        return self._postings()[1]
+
+    @property
+    def has_postings(self) -> bool:
+        """Whether the base posting lists are built (nothing reads them
+        until an engine, :attr:`max_df` or a wide-code df read asks)."""
+        return "_postings_cache" in self.__dict__
+
+    @property
+    def _df_needs_postings(self) -> bool:
+        """A base df read needs the posting lists only where the code range
+        is too wide for a table (width 0)."""
+        return not self.df_table.shape[-1]
 
     # --------------------------------------------------- quantized tables
     # int8 per-row copies of the dense leaves for fused_int8 phase-1.
@@ -405,7 +455,7 @@ class ShardedVectorIndex:
     # do NOT invalidate them -- tombstones only flip live/codes, and dead
     # rows are -inf-masked before quantized scores can matter -- so the
     # mutation paths carry the caches forward wherever the underlying
-    # vectors leaf is shared (_carry_quant).
+    # vectors leaf is shared (_carry).
     def _quant_base(self):
         """(codes (S,dp,n) int8, scale (S,dp), zero (S,dp)) of the base."""
         cached = self.__dict__.get("_quant_base_cache")
@@ -423,12 +473,16 @@ class ShardedVectorIndex:
             self.__dict__["_quant_active_cache"] = cached
         return cached
 
-    def _carry_quant(self, out: "ShardedVectorIndex", base: bool = False,
-                     active: bool = False) -> "ShardedVectorIndex":
-        """Propagate quant caches to a derived index whose corresponding
-        vectors leaves are unchanged (dataclasses.replace drops them)."""
+    def _carry(self, out: "ShardedVectorIndex", base: bool = False,
+               active: bool = False,
+               postings: bool = False) -> "ShardedVectorIndex":
+        """Propagate derived caches to an index whose corresponding leaves
+        are unchanged (dataclasses.replace drops them): the quant tables
+        where the base / active vectors are shared, the base posting lists
+        where the base codes are."""
         for flag, key in ((base, "_quant_base_cache"),
-                          (active, "_quant_active_cache")):
+                          (active, "_quant_active_cache"),
+                          (postings, "_postings_cache")):
             if flag and key in self.__dict__:
                 out.__dict__[key] = self.__dict__[key]
         return out
@@ -438,14 +492,16 @@ class ShardedVectorIndex:
         """``(path, section, array)`` for every device-resident array this
         index holds -- the seam :func:`repro.obs.device.device_bytes` walks
         for exact byte accounting.  Crucially this includes the lazily
-        derived quant-table caches (``_quant_base_cache`` /
-        ``_quant_active_cache`` / per-segment ``_quant_cache``), which are
-        real HBM residents but NOT pytree children, so a plain tree walk
-        would under-report the index by the full int8 table size."""
+        derived caches (the base posting lists once built,
+        ``_quant_base_cache`` / ``_quant_active_cache`` / per-segment
+        ``_quant_cache``), which are real HBM residents but NOT pytree
+        children, so a plain tree walk would under-report the index.  It
+        never builds one: posting lists appear only once built."""
         yield "vectors", "base", self.vectors
         yield "codes", "base", self.codes
-        yield "post_docs", "base", self.post_docs
-        yield "post_codes", "base", self.post_codes
+        if self.has_postings:
+            yield "post_docs", "base", self.post_docs
+            yield "post_codes", "base", self.post_codes
         yield "df_table", "base", self.df_table
         yield "offsets", "base", self.offsets
         yield "live", "base", self.live
@@ -478,7 +534,8 @@ class ShardedVectorIndex:
         a no-copy resharding.  The group index runs the plain 1-D search
         path (bit-identical to single-device for ``page >= n_docs``) and
         can be served, searched, and compacted independently of its
-        siblings -- the unit the cluster router batches per-group."""
+        siblings -- the unit the cluster router batches per-group.  Base
+        posting lists come along only if they were built."""
         R = self.n_replicas
         if not 0 <= g < R:
             raise ValueError(f"replica group must be in [0, {R}), got {g}")
@@ -487,12 +544,10 @@ class ShardedVectorIndex:
         devs = np.asarray(self.mesh.devices)[:, g]
         sub = Mesh(devs, (DATA_AXIS,), axis_types=(AxisType.Auto,))
         put = lambda x, spec: jax.device_put(x, NamedSharding(sub, spec))
-        return dataclasses.replace(
+        out = dataclasses.replace(
             self, mesh=sub,
             vectors=put(self.vectors, _ROW),
             codes=put(self.codes, _ROW),
-            post_docs=put(self.post_docs, _ROW),
-            post_codes=put(self.post_codes, _ROW),
             df_table=put(self.df_table, _ROW),
             offsets=put(self.offsets, P(DATA_AXIS)),
             live=put(self.live, _VEC),
@@ -507,6 +562,10 @@ class ShardedVectorIndex:
                         put(s.df_table, _ROW), s.n_rows, s.tombstones)
                 for s in self.segments),
         )
+        if self.has_postings:
+            out.__dict__["_postings_cache"] = tuple(
+                put(x, _ROW) for x in self._postings())
+        return out
 
     # -------------------------------------------------------- introspection
     def token_df(self, queries) -> jnp.ndarray:
@@ -514,17 +573,19 @@ class ShardedVectorIndex:
         what the query phase's idf weighting sees: per-shard df table reads
         (base and sealed generations) + the active buffer's code match,
         psum over ``data``.  With the eager
-        postings refresh in :meth:`delete` this counts live docs only, so
+        df refresh in :meth:`delete` this counts live docs only, so
         it is invariant under :meth:`compact` -- the pin behind the
         "idf-sensitive engines score identically across compaction"
         guarantee (and a cheap cluster debugging probe)."""
-        q = normalize(jnp.atleast_2d(jnp.asarray(queries, jnp.float32)))
-        qcodes = self.encoder.encode(q)
+        _, qcodes, _ = encode_query_rows(
+            jnp.atleast_2d(jnp.asarray(queries, jnp.float32)),
+            encoder=self.encoder, trim=None, best=None)
         seg = self.seg_capacity > 0
         sealed = tuple((s.post_docs, s.post_codes, s.df_table)
                        for s in self.segments)
+        base = self._postings() if self._df_needs_postings else (None, None)
         return _token_df_program(
-            self.post_docs, self.post_codes, self.df_table,
+            *base, self.df_table,
             self.seg_codes if seg else None, sealed, qcodes, mesh=self.mesh,
             max_abs_bucket=self.encoder.max_abs_bucket)
 
@@ -566,10 +627,10 @@ class ShardedVectorIndex:
         seal_threshold: Optional[int] = DEFAULT_SEAL_THRESHOLD,
     ) -> "ShardedVectorIndex":
         """Build the index ON the mesh: one compiled SPMD program runs
-        normalize -> encode -> ``index_best`` masking -> ``build_postings``
-        -> ``build_df_table`` per shard under ``shard_map`` -- no per-shard
-        host loop, no host round-trip (device-resident ``vectors`` are
-        resharded in place).
+        normalize -> encode -> ``index_best`` masking -> ``build_df_table``
+        per shard under ``shard_map`` -- no per-shard host loop, no host
+        round-trip (device-resident ``vectors`` are resharded in place).
+        No posting list is sorted: they are built on demand.
 
         Bit-identical to ``VectorIndex.build(vectors, ...)`` followed by
         :meth:`from_index` (pinned by tests/test_build_parity.py): every
@@ -595,15 +656,13 @@ class ShardedVectorIndex:
 
         with watch_region("build.program",
                           sig=(int(ns), int(dp), int(n_feat))):
-            vecs, codes, pdocs, pcodes, table = _build_program(
+            vecs, codes, table = _build_program(
                 raw, lv, mesh=mesh, encoder=encoder, index_best=index_best)
         _count_df_table(table)
 
         return cls(
             vectors=vecs,
             codes=codes,
-            post_docs=pdocs,
-            post_codes=pcodes,
             df_table=table,
             offsets=_put(mesh, cls._offsets(ns, dp), P(DATA_AXIS)),
             live=lv,
@@ -628,8 +687,8 @@ class ShardedVectorIndex:
                    ) -> "ShardedVectorIndex":
         """Partition an existing single-device index across ``mesh``'s
         ``data`` axis (contiguous ranges, ES-style doc-sharding).  The
-        per-shard posting lists are rebuilt in ONE compiled SPMD program
-        (argsort per shard under ``shard_map``) -- not a host loop -- and
+        per-shard df tables are counted in ONE compiled SPMD program (per
+        shard under ``shard_map``) -- not a host loop -- and
         device-resident leaves reshard without a host numpy round-trip.
 
         On a ``(data, replica)`` mesh every leaf's spec leaves the
@@ -650,12 +709,11 @@ class ShardedVectorIndex:
         vectors = _put(mesh, vectors.reshape(ns, dp, n_feat), _ROW)
         codes = _put(mesh, codes.reshape(ns, dp, -1), _ROW)
 
-        # per-shard inverted indexes in one SPMD program: the sentinel sorts
-        # to the tail of every posting list, so padded docs are invisible to
-        # range lookups
-        with watch_region("build.postings", sig=tuple(codes.shape)):
-            pdocs, pcodes, table = _shard_postings(
-                codes, mesh, index.encoder.max_abs_bucket)
+        # per-shard df tables in one SPMD program: padded docs carry the
+        # sentinel and count under its entry alone
+        with watch_region("build.df_table", sig=tuple(codes.shape)):
+            table = _shard_df_table(codes, mesh,
+                                    index.encoder.max_abs_bucket)
 
         offsets = cls._offsets(ns, dp)
         counts = np.clip(n - offsets, 0, dp)        # real rows per shard
@@ -663,8 +721,6 @@ class ShardedVectorIndex:
         return cls(
             vectors=vectors,
             codes=codes,
-            post_docs=pdocs,
-            post_codes=pcodes,
             df_table=table,
             offsets=_put(mesh, offsets, P(DATA_AXIS)),
             live=_put(mesh, live, _VEC),
@@ -770,7 +826,7 @@ class ShardedVectorIndex:
             seg_vectors=svec, seg_codes=scod, seg_gids=sgid, seg_live=sliv,
             n_appended=self.n_appended + m,
         )
-        out = self._carry_quant(out, base=True)  # base leaves untouched
+        out = self._carry(out, base=True, postings=True)  # base untouched
         if (out.seal_threshold is not None
                 and out.n_active >= out.seal_threshold):
             out = out._seal_active()
@@ -780,8 +836,8 @@ class ShardedVectorIndex:
         """Seal the active append buffer into an immutable :class:`Segment`.
 
         The buffer is truncated to its exact round-robin width, gets its
-        own mini posting table and df table (the same one-program SPMD
-        build the base build and :meth:`delete` use), and joins
+        own mini posting table and df table (the one-program SPMD builds
+        :meth:`delete` and :meth:`merge_segments` use too), and joins
         ``segments``; the next :meth:`add_documents` opens a fresh active
         buffer whose geometric growth ladder restarts from empty.  A pure
         function of the op history, so translog replay re-seals at
@@ -816,22 +872,24 @@ class ShardedVectorIndex:
             self, segments=self.segments + (seg,),
             seg_vectors=ev, seg_codes=ec, seg_gids=eg, seg_live=el,
             seg_base=self.n_appended, active_tombstones=0)
-        return self._carry_quant(out, base=True)
+        return self._carry(out, base=True, postings=True)
 
     def delete(self, ids) -> "ShardedVectorIndex":
         """Tombstone documents by global id -> a new index.
 
         The doc's ``live`` flag goes False and its codes become the
         sentinel, so the ``codes``/``onehot`` engines skip it outright and
-        the ``live`` mask blocks it from every result page.  Base posting
-        lists and df tables are REBUILT in the same one-program SPMD argsort
-        the build uses (the sentinel sorts every tombstone to the list
-        tails), so document frequencies are exact immediately -- idf
-        weights, and therefore idf-sensitive phase-1 scores, are identical
-        before and after :meth:`compact`.  That is stricter than Lucene
-        (which lets df count deleted docs until a merge) at the cost of one
-        argsort per delete batch -- a control-plane price, not a query-path
-        one.
+        the ``live`` mask blocks it from every result page.  The affected
+        df tables are COUNTED AGAIN off the new codes (a tombstone counts
+        only under the sentinel), so document frequencies are exact
+        immediately -- idf weights, and therefore idf-sensitive phase-1
+        scores, are identical before and after :meth:`compact`.  That is
+        stricter than Lucene (which lets df count deleted docs until a
+        merge) at the cost of one pass over the codes per delete batch -- a
+        control-plane price, not a query-path one.  Base posting lists, if
+        built, are dropped and sorted again when next read (the sentinel
+        sorts every tombstone to the list tails); a sealed segment's mini
+        posting lists are sorted again at once.
         Deleting an already-dead or padded id is a no-op for that id (and
         does not count toward ``shard_tombstones``).
         """
@@ -853,12 +911,10 @@ class ShardedVectorIndex:
             new["live"] = _put(self.mesh, self.live.at[s, r].set(False), _VEC)
             new["codes"] = _put(self.mesh,
                                 self.codes.at[s, r].set(sentinel), _ROW)
-            # exact-df postings refresh: one SPMD argsort over the updated
-            # codes drops the tombstones out of every posting list and df
-            # table
-            new["post_docs"], new["post_codes"], new["df_table"] = \
-                _shard_postings(new["codes"], self.mesh,
-                                self.encoder.max_abs_bucket)
+            # exact-df refresh: the tables counted again off the updated
+            # codes, where the tombstones carry the sentinel
+            new["df_table"] = _shard_df_table(new["codes"], self.mesh,
+                                              self.encoder.max_abs_bucket)
         app = ids[ids >= self.n_docs]
         if app.size:
             segs = list(self.segments)
@@ -904,18 +960,21 @@ class ShardedVectorIndex:
         old = (np.asarray(self.shard_tombstones, np.int64)
                if self.shard_tombstones else np.zeros(self.n_shards, np.int64))
         new["shard_tombstones"] = tuple(int(x) for x in old + dead)
-        # deletes never touch a vectors leaf -- every quant table survives
-        return self._carry_quant(dataclasses.replace(self, **new),
-                                 base=True, active=True)
+        # deletes never touch a vectors leaf -- every quant table survives;
+        # the base posting lists survive where no base code changed
+        return self._carry(dataclasses.replace(self, **new),
+                           base=True, active=True,
+                           postings="codes" not in new)
 
     def compact(self) -> "ShardedVectorIndex":
         """Fold append segments and tombstones back into a clean base by
         re-running the on-device sharded build over the live doc table.
 
         Global ids are STABLE: the new base spans ``[0, n_ids)`` in old-id
-        order, with dead ids carried as sentinel-coded padding rows --
-        posting lists are tombstone-free again and df is exact.  The new
-        index has ``n_appended == 0`` and zero-width segments.
+        order, with dead ids carried as sentinel-coded padding rows -- the
+        df tables are counted afresh and exact; posting lists are sorted
+        again only if something reads them.  The new index has
+        ``n_appended == 0`` and zero-width segments.
         """
         ns, dp, n_feat = self.n_shards, self.docs_per_shard, self.n_features
         flat_v = self.vectors.reshape(ns * dp, n_feat)[: self.n_docs]
@@ -1004,8 +1063,9 @@ class ShardedVectorIndex:
         before, after = self.segments[:start], self.segments[start + count:]
         if n_live == 0:
             # every row in the run was dead: the generations just vanish
-            return dataclasses.replace(
-                self, segments=before + after, shard_tombstones=stones_t)
+            return self._carry(dataclasses.replace(
+                self, segments=before + after, shard_tombstones=stones_t),
+                base=True, active=True, postings=True)
 
         w = -(-n_live // ns)
         mv = np.zeros((ns, w, n_feat), np.float32)
@@ -1027,9 +1087,9 @@ class ShardedVectorIndex:
                 dcod, self.mesh, self.encoder.max_abs_bucket)
         merged = Segment(dvec, dcod, dgid, dliv, pdocs, pcodes, table,
                          n_rows=n_live, tombstones=0)
-        return dataclasses.replace(
+        return self._carry(dataclasses.replace(
             self, segments=before + (merged,) + after,
-            shard_tombstones=stones_t)
+            shard_tombstones=stones_t), base=True, active=True, postings=True)
 
     # ------------------------------------------------------------------ search
     def search(
@@ -1111,10 +1171,8 @@ class ShardedVectorIndex:
                     src[c * B:(c + 1) * B] = np.arange(j * B, (j + 1) * B)
                 zero = jnp.zeros((1, q.shape[1]), jnp.float32)
                 q = jnp.concatenate([q, zero])[jnp.asarray(src)]
-            q = normalize(q)
-            qcodes = self.encoder.encode(q)
-            mask = expand_mask(feature_mask(q, trim=trim, best=best),
-                               qcodes.shape[-1])
+            q, qcodes, mask = encode_query_rows(q, encoder=self.encoder,
+                                                trim=trim, best=best)
             if profile is not None:
                 jax.block_until_ready((q, qcodes, mask))
                 t_now = time.monotonic()
@@ -1135,13 +1193,20 @@ class ShardedVectorIndex:
         # table (mixing quantized-cosine and idf-sum scales inside one
         # top_k would be meaningless); other engines pass no quant leaves
         quant = engine == "fused_int8"
+        # the base posting lists go in only where something reads them: the
+        # postings engine, or an idf df read with no table to read
+        if engine == "postings" or (weighting == "idf" and not quant
+                                    and self._df_needs_postings):
+            post_docs, post_codes = self._postings()
+        else:
+            post_docs = post_codes = None
         with annotation("repro.search.query_phase"), watch_region(
                 "search.query_phase",
                 sig=(tuple(q.shape), engine, weighting, int(page_loc),
                      int(L), int(k) if merge == "stream" else 0, merge,
                      len(self.segments), bool(seg))):
             gids, scores = _query_phase(
-                self.vectors, self.codes, self.post_docs, self.post_codes,
+                self.vectors, self.codes, post_docs, post_codes,
                 self.df_table, self.offsets, self.live,
                 self.seg_vectors if seg else None,
                 self.seg_codes if seg else None,
@@ -1212,12 +1277,12 @@ def _build_program(raw, live, *, mesh, encoder, index_best):
     """THE on-device build: one SPMD program, whole pipeline per shard.
 
     Every stage is row-wise (normalize, encode, best-mask) or
-    column-independent over the local rows (the posting argsort, the df
-    table), so each shard's block produces bit-identical results to the
-    same rows inside a single-device build -- which is exactly the parity
-    the property suite pins.  ``live=False`` rows (pads, carried
-    tombstones) become zero vectors with sentinel codes, sorting to the
-    tail of every posting list.
+    column-independent over the local rows (the df table's counts), so
+    each shard's block produces bit-identical results to the same rows
+    inside a single-device build -- which is exactly the parity the
+    property suite pins.  ``live=False`` rows (pads, carried tombstones)
+    become zero vectors with sentinel codes, counted under the table's
+    sentinel entry alone.  No posting list is sorted here.
     """
     from .shmap import shard_map
 
@@ -1231,13 +1296,11 @@ def _build_program(raw, live, *, mesh, encoder, index_best):
             codes = index_best_codes(v, codes, index_best, sentinel)
         codes = jnp.where(lv[:, None], codes,
                           jnp.asarray(sentinel, codes.dtype))
-        p = build_postings(codes)
-        table = build_df_table(p, encoder.max_abs_bucket, sentinel)
-        return (v[None], codes[None], p.post_docs[None], p.post_codes[None],
-                table[None])
+        table = build_df_table(codes, encoder.max_abs_bucket, sentinel)
+        return v[None], codes[None], table[None]
 
     fn = shard_map(local, mesh=mesh, in_specs=(_ROW, _VEC),
-                   out_specs=(_ROW,) * 5, check=False)
+                   out_specs=(_ROW,) * 3, check=False)
     return fn(raw, live)
 
 
@@ -1286,31 +1349,50 @@ def _quantize_program(vectors, *, mesh):
     return fn(vectors)
 
 
+@partial(jax.jit, static_argnames=("mesh",))
+def _postings_program(codes, *, mesh):
+    """Per-shard posting lists in one SPMD program: (post_docs,
+    post_codes), each (S, C, W), one stable argsort per shard."""
+    from .shmap import shard_map
+
+    def local(c):
+        p = build_postings(c[0])
+        return p.post_docs[None], p.post_codes[None]
+
+    fn = shard_map(local, mesh=mesh, in_specs=(_ROW,),
+                   out_specs=(_ROW, _ROW), check=False)
+    return fn(codes)
+
+
 @partial(jax.jit, static_argnames=("mesh", "max_abs_bucket"))
-def _postings_program(codes, *, mesh, max_abs_bucket):
-    """Per-shard posting lists and df table in one SPMD program (codes
-    already exist, only the argsort and the table's lookups run per
-    shard)."""
+def _df_table_program(codes, *, mesh, max_abs_bucket):
+    """Per-shard df tables counted off the codes in one SPMD program:
+    (S, C, W) int32."""
     from .shmap import shard_map
 
     sentinel = int(_SENTINEL[codes.dtype])
 
     def local(c):
-        p = build_postings(c[0])
-        table = build_df_table(p, max_abs_bucket, sentinel)
-        return p.post_docs[None], p.post_codes[None], table[None]
+        return build_df_table(c[0], max_abs_bucket, sentinel)[None]
 
-    fn = shard_map(local, mesh=mesh, in_specs=(_ROW,),
-                   out_specs=(_ROW, _ROW, _ROW), check=False)
+    fn = shard_map(local, mesh=mesh, in_specs=(_ROW,), out_specs=_ROW,
+                   check=False)
     return fn(codes)
+
+
+def _shard_df_table(codes, mesh: Mesh, max_abs_bucket: int):
+    """The df table of every shard of ``codes`` (S, W, C), counted."""
+    table = _df_table_program(codes, mesh=mesh,
+                              max_abs_bucket=max_abs_bucket)
+    _count_df_table(table)
+    return table
 
 
 def _shard_postings(codes, mesh: Mesh, max_abs_bucket: int):
     """(post_docs, post_codes, df_table) of every shard of ``codes``
-    (S, W, C): :func:`_postings_program`, with the table counted."""
-    out = _postings_program(codes, mesh=mesh, max_abs_bucket=max_abs_bucket)
-    _count_df_table(out[2])
-    return out
+    (S, W, C): a sealed segment's mini posting lists and its df table."""
+    pdocs, pcodes = _postings_program(codes, mesh=mesh)
+    return pdocs, pcodes, _shard_df_table(codes, mesh, max_abs_bucket)
 
 
 def _count_df_table(table) -> None:
@@ -1449,9 +1531,10 @@ def _query_phase(vectors, codes, post_docs, post_codes, df_table, offsets,
     Under ``weighting="idf"`` (scope ``df_lookup``) each shard reads its
     tokens' df from its ``df_table`` in one pass (``table_df``, a
     compare-and-select over the codes the table holds, integer-identical to
-    the posting-range lookup the table was built with); a shard whose code
-    range is too wide for a table (width 0) runs that lookup per token
-    instead.
+    a posting-range lookup); a shard whose code range is too wide for a
+    table (width 0) runs that lookup per token instead.  ``post_docs`` /
+    ``post_codes`` are ``None`` unless the engine (``postings``) or that
+    lookup reads them.
 
     Appended docs live in generations: ``sealed`` is a tuple of
     ``(vectors, codes, gids, live, post_docs, post_codes, df_table)``
@@ -1490,12 +1573,17 @@ def _query_phase(vectors, codes, post_docs, post_codes, df_table, offsets,
     n_sealed = len(sealed)
     widths = tuple(t[0].shape[1] for t in sealed)
     quant = engine == "fused_int8"
+    has_post = post_docs is not None
 
     sentinel = int(_SENTINEL[codes.dtype])
 
     def local(*args):
-        vec, codes, pdocs, pcodes, dft, off, lv = args[:7]
-        rest = args[7:]
+        vec, codes, dft, off, lv = args[:5]
+        rest = args[5:]
+        postings = None
+        if has_post:
+            postings = Postings(rest[0][0], rest[1][0], dp)
+            rest = rest[2:]
         if G:
             svec, scod, sgid, sliv = (x[0] for x in rest[:4])
             rest = rest[4:]
@@ -1513,7 +1601,6 @@ def _query_phase(vectors, codes, post_docs, post_codes, df_table, offsets,
             rest = rest[n_sealed * 3:]
         q, qcodes, mask, n_ids = rest
         vec, codes, lv = vec[0], codes[0], lv[0]
-        postings = Postings(pdocs[0], pcodes[0], dp)
         off = off[0]
 
         if quant:
@@ -1661,8 +1748,11 @@ def _query_phase(vectors, codes, post_docs, post_codes, df_table, offsets,
 
     rep = REPLICA_AXIS in mesh.axis_names
     qaxis = REPLICA_AXIS if rep else None
-    args = [vectors, codes, post_docs, post_codes, df_table, offsets, live]
-    specs = [_ROW, _ROW, _ROW, _ROW, _ROW, P(DATA_AXIS), _VEC]
+    args = [vectors, codes, df_table, offsets, live]
+    specs = [_ROW, _ROW, _ROW, P(DATA_AXIS), _VEC]
+    if has_post:
+        args += [post_docs, post_codes]
+        specs += [_ROW, _ROW]
     if G:
         args += [seg_vectors, seg_codes, seg_gids, seg_live]
         specs += [_ROW, _ROW, _VEC, _VEC]
@@ -1764,27 +1854,35 @@ def _token_df_program(post_docs, post_codes, df_table, seg_codes, sealed,
     """Global per-token df, the query phase's idf input verbatim: per-shard
     df table read (``table_df``: base + each sealed generation's own
     table) plus the active buffer's code match, psum over ``data``.
-    ``sealed`` is a tuple of (post_docs, post_codes, df_table) triples.
-    Queries are replicated (df is identical in every replica group)."""
+    ``sealed`` is a tuple of (post_docs, post_codes, df_table) triples; the
+    base's ``post_docs`` / ``post_codes`` are ``None`` where its table is
+    read.  Queries are replicated (df is identical in every replica
+    group)."""
     from .shmap import shard_map
 
     G = seg_codes is not None
-    sentinel = int(_SENTINEL[post_codes.dtype])
+    has_post = post_docs is not None
+    sentinel = int(_SENTINEL[qcodes.dtype])
 
     def local(*args):
         *leaves, qc = args
         if G:
             *leaves, sc = leaves
-        df = 0
-        for i in range(0, len(leaves), 3):      # base, then sealed triples
-            pd, pc, dt = (x[0] for x in leaves[i:i + 3])
+        leaves = [x[0] for x in leaves]
+        base = None
+        if has_post:
+            pd, pc, *leaves = leaves
+            base = Postings(pd, pc, pc.shape[-1])
+        df = table_df(leaves[0], base, qc, max_abs_bucket, sentinel)
+        for i in range(1, len(leaves), 3):      # sealed triples
+            pd, pc, dt = leaves[i:i + 3]
             df = df + table_df(dt, Postings(pd, pc, pc.shape[-1]), qc,
                                max_abs_bucket, sentinel)
         if G:
             df = df + code_df(sc[0], qc)
         return jax.lax.psum(df, DATA_AXIS)
 
-    args = [post_docs, post_codes, df_table]
+    args = ([post_docs, post_codes] if has_post else []) + [df_table]
     for leaves in sealed:
         args += list(leaves)
     args += [seg_codes] if G else []

@@ -3,7 +3,10 @@
 A real deployment fronts the TPU program with a request batcher: incoming
 query vectors are buffered until ``max_batch`` or ``max_wait_s`` (whichever
 first), padded to the compiled batch shape, executed as ONE jitted search,
-and scattered back to their futures.  This mirrors the paper's observation
+and scattered back to their futures.  The worker dispatches the next
+batch as soon as it forms and only then reads the previous one back, so
+up to two batches are on the device and the device does not wait on the
+host between them.  This mirrors the paper's observation
 (Table 3) that parallel querying trades per-request latency for throughput --
 here the trade is explicit: batch 1 = lowest latency, batch N = N-fold
 throughput at ~constant step time (the TPU is batch-insensitive until the
@@ -91,6 +94,29 @@ from repro.obs.tracing import annotating, annotation
 __all__ = ["BatchedSearchEngine"]
 
 
+# how often a worker waiting for the next batch looks whether the batch
+# it holds on the device is done (it reads that one back once it is)
+_HELD_POLL_S = 0.0005
+
+
+class _InFlight:
+    """One dispatched batch, from its dispatch to its readback."""
+
+    __slots__ = ("batch", "index", "t_deq", "t_dispatch", "ids", "scores",
+                 "prof", "error", "span")
+
+    def __init__(self, batch, index, t_deq):
+        self.batch, self.index, self.t_deq = batch, index, t_deq
+        self.t_dispatch = t_deq     # overwritten once the batch is built
+        self.ids = self.scores = self.prof = self.error = self.span = None
+
+    def ready(self) -> bool:
+        """Its answers can be read back without waiting on the device."""
+        return self.error is not None or all(
+            getattr(a, "is_ready", lambda: True)()
+            for a in (self.ids, self.scores))
+
+
 def _accepts_profile(index) -> bool:
     """Whether ``index.search`` takes the ``profile`` kwarg (the engine
     is index-polymorphic; test doubles and plain callables may not).
@@ -138,7 +164,7 @@ class BatchedSearchEngine:
         # donating a buffer a dispatched program still reads would be a
         # use-after-free)
         self.donate_ingest = donate_ingest
-        self._serving = None
+        self._serving: list = []
         # observability: metrics series carry the replica-group label when
         # this batcher fronts one group of a cluster; instruments are
         # cached here so the worker pays one lock-op per record, not a
@@ -251,7 +277,7 @@ class BatchedSearchEngine:
             # the engine owns the only reference unless the in-flight
             # batch snapshotted exactly this object
             donate = (self.donate_ingest
-                      and self.index is not self._serving
+                      and not any(s is self.index for s in self._serving)
                       and "donate" in inspect.signature(add).parameters)
             t0 = time.monotonic()
             with self.compile_watch.region(
@@ -344,13 +370,28 @@ class BatchedSearchEngine:
     def _run(self):
         # with an annotating tracer every phase of the worker's loop (and
         # the index's search phases under it) opens a profiler span, each
-        # at the clock read its metrics and trace spans use
+        # at the clock read its metrics and trace spans use.
+        # Two batches may be on the device at once: batch n+1 is
+        # dispatched as soon as it forms, and only then is batch n read
+        # back and resolved, so the device runs n+1 through the host's
+        # readback, resolve and next dispatch instead of idling
         with annotating(self.tracer is not None and self.tracer.annotate):
-            while self._serve_next():
-                pass
+            held = None                 # dispatched, not yet read back
+            while True:
+                with annotation("repro.engine.wait"):
+                    got = self._next_batch(held)
+                if got is None:         # closed and drained
+                    if held is not None:
+                        self._finish(held)
+                    return
+                new = self._dispatch(*got) if got[0] else None
+                if held is not None:
+                    self._finish(held)
+                held = new
 
-    def _next_batch(self):
-        """Block until a batch can form -> (batch, index, t_deq), or None
+    def _next_batch(self, held: "Optional[_InFlight]"):
+        """Block until a batch can form -> (batch, index, t_deq); an empty
+        batch once ``held`` is done before the next batch formed; None
         once closed and drained."""
         with self._lock:
             # the batch deadline anchors to the OLDEST queued request's
@@ -362,14 +403,19 @@ class BatchedSearchEngine:
             # immediately (deadline already stale) and the measured
             # wait was unknowable
             while len(self._queue) < self.batch_size and not self._stop:
+                if held is not None and held.ready():
+                    return [], None, None
                 now = time.monotonic()
                 if self._queue:
                     deadline = self._queue[0][2] + self.max_wait_s
                     if now >= deadline:
                         break
-                    self._lock.wait(timeout=deadline - now)
+                    wait = deadline - now
                 else:
-                    self._lock.wait(timeout=self.max_wait_s)
+                    wait = self.max_wait_s
+                if held is not None:    # look again when it may be done
+                    wait = min(wait, _HELD_POLL_S)
+                self._lock.wait(timeout=wait)
             if self._stop and not self._queue:
                 return None
             t_deq = time.monotonic()
@@ -377,85 +423,89 @@ class BatchedSearchEngine:
             del self._queue[: len(batch)]
             # snapshot under the lock: a hot swap after this point
             # applies to the NEXT batch, this one finishes on `index`.
-            # _serving publishes the snapshot so a concurrent
-            # donate-ingest knows these buffers are being read
+            # _serving publishes the snapshots of the batches in flight,
+            # so a concurrent donate-ingest knows these buffers are read
             index = self.index
-            self._serving = index if batch else None
-            self._inflight = len(batch)
+            self._serving.append(index)
+            self._inflight += len(batch)
         return batch, index, t_deq
 
-    def _serve_next(self) -> bool:
-        """Serve one batch; False once closed and drained."""
-        with annotation("repro.engine.wait"):
-            got = self._next_batch()
-        if got is None:
-            return False
-        batch, index, t_deq = got
-        if not batch:
-            return True
-        # a failing search must not kill the worker: every queued and
-        # in-flight future would strand (resolve only by caller
-        # timeout) -- fail this batch's futures, serve the next batch
+    def _dispatch(self, batch, index, t_deq) -> "_InFlight":
+        """Form one batch and dispatch its search -> the batch in flight.
+        A failing search must not kill the worker: every queued and
+        in-flight future would strand (resolve only by caller timeout) --
+        the error is kept and fails this batch's futures alone."""
+        d = _InFlight(batch, index, t_deq)
         try:
-            error = prof = ids = scores = None
-            t_dispatch = t_deq    # overwritten once the batch is built
-            try:
-                with annotation("repro.engine.batch_form"):
-                    # one t_deq for the whole batch: the queue-wait each
-                    # metric and trace span reports is (t_deq - enqueue),
-                    # same clock read; one lock acquisition for the whole
-                    # batch's waits
-                    self._h_wait.observe_many(
-                        [t_deq - it[2] for it in batch])
-                    self._h_occupancy.observe(len(batch) / self.batch_size)
-                    qs = np.stack([it[0] for it in batch])
-                    pad = self.batch_size - qs.shape[0]
-                    if pad:
-                        qs = np.concatenate(
-                            [qs, np.zeros((pad, qs.shape[1]), qs.dtype)])
-                    kwargs = {"merge": self.merge} if self.merge else {}
-                    if self.max_postings is not None:
-                        kwargs["max_postings"] = self.max_postings
-                    if any(it[4] for it in batch):
-                        # ONE dispatch subtree shared by every profiled
-                        # request in the batch (they share the dispatch);
-                        # the index annotates its phases into it when it
-                        # supports the profile kwarg
-                        prof = ProfileNode(
-                            "dispatch", batch_size=len(batch),
-                            engine=self.engine, k=self.k, page=self.page,
-                            **({} if self.group is None
-                               else {"group": self.group}))
-                        if _accepts_profile(index):
-                            kwargs["profile"] = prof
-                    t_dispatch = time.monotonic()
-                with annotation("repro.engine.dispatch"):
-                    with self.compile_watch.region(
-                            "engine.dispatch",
-                            sig=(qs.shape, str(qs.dtype), self.engine,
-                                 self.k, self.page,
-                                 self.merge or "gather")):
-                        ids, scores = index.search(
-                            jnp.asarray(qs), k=self.k, page=self.page,
-                            trim=self.trim, engine=self.engine,
-                            **kwargs,
-                        )
-                        with annotation("repro.engine.readback"):
-                            ids, scores = np.asarray(ids), np.asarray(scores)
-            except Exception as exc:  # noqa: BLE001 - fwd to futures
-                t_done = time.monotonic()
-                error = exc
-            else:
-                t_done = time.monotonic()
-                if prof is not None:
-                    prof.duration_s = t_done - t_dispatch
+            with annotation("repro.engine.batch_form"):
+                # one t_deq for the whole batch: the queue-wait each
+                # metric and trace span reports is (t_deq - enqueue),
+                # same clock read; one lock acquisition for the whole
+                # batch's waits
+                self._h_wait.observe_many([t_deq - it[2] for it in batch])
+                self._h_occupancy.observe(len(batch) / self.batch_size)
+                qs = np.stack([it[0] for it in batch])
+                pad = self.batch_size - qs.shape[0]
+                if pad:
+                    qs = np.concatenate(
+                        [qs, np.zeros((pad, qs.shape[1]), qs.dtype)])
+                kwargs = {"merge": self.merge} if self.merge else {}
+                if self.max_postings is not None:
+                    kwargs["max_postings"] = self.max_postings
+                if any(it[4] for it in batch):
+                    # ONE dispatch subtree shared by every profiled
+                    # request in the batch (they share the dispatch);
+                    # the index annotates its phases into it when it
+                    # supports the profile kwarg
+                    d.prof = ProfileNode(
+                        "dispatch", batch_size=len(batch),
+                        engine=self.engine, k=self.k, page=self.page,
+                        **({} if self.group is None
+                           else {"group": self.group}))
+                    if _accepts_profile(index):
+                        kwargs["profile"] = d.prof
+                d.t_dispatch = time.monotonic()
+            # the dispatch span runs to the end of this batch's readback,
+            # past the next batch's dispatch (spans of two batches overlap)
+            d.span = annotation("repro.engine.dispatch")
+            d.span.__enter__()
+            with self.compile_watch.region(
+                    "engine.dispatch",
+                    sig=(qs.shape, str(qs.dtype), self.engine, self.k,
+                         self.page, self.merge or "gather")):
+                d.ids, d.scores = index.search(
+                    jnp.asarray(qs), k=self.k, page=self.page,
+                    trim=self.trim, engine=self.engine, **kwargs)
+        except Exception as exc:  # noqa: BLE001 - fwd to futures
+            d.error = exc
+        return d
+
+    def _finish(self, d: "_InFlight") -> None:
+        """Read one dispatched batch back and resolve its futures."""
+        ids = scores = None
+        try:
+            if d.error is None:
+                try:
+                    with annotation("repro.engine.readback"):
+                        ids, scores = np.asarray(d.ids), np.asarray(d.scores)
+                except Exception as exc:  # noqa: BLE001 - fwd to futures
+                    d.error = exc
+            t_done = time.monotonic()
+            if d.span is not None:
+                d.span.__exit__(None, None, None)
+            if d.prof is not None and d.error is None:
+                d.prof.duration_s = t_done - d.t_dispatch
             with annotation("repro.engine.resolve"):
-                self._resolve(batch, error, ids, scores, prof, t_deq,
-                              t_dispatch, t_done)
+                self._resolve(d.batch, d.error, ids, scores, d.prof,
+                              d.t_deq, d.t_dispatch, t_done)
         finally:
-            self._inflight = 0
-            self._serving = None
-        return True
+            with self._lock:
+                self._inflight -= len(d.batch)
+                # by identity: two batches in flight may share a snapshot
+                for i, s in enumerate(self._serving):
+                    if s is d.index:
+                        del self._serving[i]
+                        break
 
     def _resolve(self, batch, error, ids, scores, prof, t_deq, t_dispatch,
                  t_done) -> None:

@@ -49,10 +49,11 @@ referenced blob unlinked under it.
   ingest/merge used: active rows by their append offset
   (``gid - n_docs - seg_base``), sealed-segment rows by gid rank,
   round-robin -- search parity at ``page >= n_ids`` holds on any mesh.
-* per-shard posting lists and df tables (base and per-segment mini
-  tables) are rebuilt with the same one-program SPMD argsort
-  (``_postings_program``) the live index uses, so they are bit-identical
-  to the committed index's on the same mesh shape.
+* per-shard df tables (base and per segment) and the segments' mini
+  posting lists are rebuilt with the same one-program SPMD programs the
+  live index uses, so they are bit-identical to the committed index's on
+  the same mesh shape.  Base posting lists are never stored: the restored
+  index, like a fresh build, sorts them only when something reads them.
 
 ``shard_tombstones`` is exact on a same-shard-count restore; restoring to
 a different shard count redistributes the writer's TOTAL round-robin
@@ -81,7 +82,8 @@ from repro.core.encoding import (CombinedEncoder, Encoder, IntervalEncoder,
                                  RoundingEncoder)
 from repro.core.search import _SENTINEL
 from repro.dist.shard_index import (Segment, ShardedVectorIndex,
-                                    _put, _ROW, _shard_postings, _VEC)
+                                    _put, _ROW, _shard_df_table,
+                                    _shard_postings, _VEC)
 from repro.dist.sharding import DATA_AXIS
 
 __all__ = ["CommitPoint", "write_commit", "latest_commit", "restore",
@@ -413,9 +415,11 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
     rules ingest/merge used (active rows by append offset, sealed rows by
     gid rank, round-robin) and places each leaf with one ``device_put``
     (scatter-free -- see module docstring for the replica-mesh GSPMD
-    gotcha); postings and df tables (base + per-segment mini tables) are
-    rebuilt by the same SPMD argsort the live paths use.  On any shape,
-    search results match at ``page >= n_ids``.
+    gotcha); df tables (base and per segment) are counted again and the
+    segments' mini posting lists sorted again by the same SPMD programs
+    the live paths use.  The base posting lists are not stored: like a
+    fresh build, the restored index sorts them only if something reads
+    them.  On any shape, search results match at ``page >= n_ids``.
     """
     meta = commit.meta
     store_dir = commit.data_path
@@ -445,8 +449,7 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
     vectors = _put(mesh, vec.reshape(ns, dp, nf), _ROW)
     codes = _put(mesh, codes.reshape(ns, dp, C), _ROW)
     live = _put(mesh, live.reshape(ns, dp), _VEC)
-    pdocs, pcodes, table = _shard_postings(codes, mesh,
-                                           encoder.max_abs_bucket)
+    table = _shard_df_table(codes, mesh, encoder.max_abs_bucket)
 
     # ----- active append buffer
     if files["active"] is not None and same_shards:
@@ -519,8 +522,6 @@ def restore(commit: CommitPoint, mesh: Mesh) -> ShardedVectorIndex:
     return ShardedVectorIndex(
         vectors=vectors,
         codes=codes,
-        post_docs=pdocs,
-        post_codes=pcodes,
         df_table=table,
         offsets=_put(mesh, ShardedVectorIndex._offsets(ns, dp),
                      P(DATA_AXIS)),

@@ -12,9 +12,10 @@ Multi-device sweeps run in a subprocess (the virtual-device flag must
 precede jax initialisation, same pattern as test_shard_index.py): one
 4-device and one 8-device mesh sweep, each covering even AND ragged splits
 (two fixed anchor examples guarantee both) plus seeded random draws.
-A separate subprocess pins the one-compiled-program claim: ``build_postings``
-is traced exactly once per build, for any shard count -- no per-shard host
-loop.
+A separate subprocess pins the one-compiled-program claim: the df table's
+``build_df_table`` is traced exactly once per build, for any shard count --
+no per-shard host loop -- and ``build_postings`` only when the posting
+lists are first read.
 """
 
 import os
@@ -150,10 +151,13 @@ def test_build_parity_sweep_8dev():
 
 
 def test_build_is_one_compiled_program():
-    """``build_sharded`` (and the loop-free ``from_index``) trace
-    ``build_postings`` exactly ONCE regardless of shard count: the build is
-    one compiled SPMD program, not an S-iteration host loop.  Fresh shapes
-    guarantee a fresh trace (jit caching would otherwise hide calls)."""
+    """``build_sharded`` (and the loop-free ``from_index``) trace the df
+    table's ``build_df_table`` exactly ONCE regardless of shard count, and
+    ``build_postings`` never: the build is one compiled SPMD program, not
+    an S-iteration host loop, and sorts no posting list.  Reading the
+    posting lists then traces ``build_postings`` once, in one program too.
+    Fresh shapes guarantee a fresh trace (jit caching would otherwise hide
+    calls)."""
     _run_subprocess(r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -162,17 +166,23 @@ import repro.dist.shard_index as si
 from repro.core import VectorIndex
 from repro.launch.mesh import make_shard_mesh
 
-calls = []
-orig = si.build_postings
+calls, tables = [], []
+orig, orig_table = si.build_postings, si.build_df_table
 si.build_postings = lambda c: (calls.append(1), orig(c))[1]
+si.build_df_table = lambda *a: (tables.append(1), orig_table(*a))[1]
 
 V = np.random.default_rng(3).normal(size=(37, 9)).astype(np.float32)
 mesh = make_shard_mesh(4)
 dev = si.ShardedVectorIndex.build_sharded(V, mesh)
-assert len(calls) == 1, f"build_sharded traced build_postings {len(calls)}x"
+assert len(tables) == 1, f"build_sharded traced build_df_table {len(tables)}x"
+assert not calls, f"build_sharded traced build_postings {len(calls)}x"
+dev.post_docs
+assert len(calls) == 1, f"post_docs traced build_postings {len(calls)}x"
 
 calls.clear()
+tables.clear()
 si.ShardedVectorIndex.from_index(VectorIndex.build(V[:35, :8]), mesh)
-assert len(calls) == 1, f"from_index traced build_postings {len(calls)}x"
+assert len(tables) == 1, f"from_index traced build_df_table {len(tables)}x"
+assert not calls, f"from_index traced build_postings {len(calls)}x"
 print("OK")
 """)

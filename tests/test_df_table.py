@@ -2,6 +2,9 @@
 
 The pinned invariants:
 
+* the df table counted off the codes (``build_df_table``) is
+  integer-identical to the table the posting-range lookup fills, sentinel
+  entry included;
 * a df table read (``table_df``) is integer-identical to the posting-range
   lookup (``df_lookup``) and to the dense count (``code_df``) for every
   code a column can be asked for: every legal bucket, the sentinel, and
@@ -15,7 +18,11 @@ The pinned invariants:
 * search answers are identical bit for bit with the table and with the
   per-query lookup it replaced, and an encoder whose code range is too
   wide for a table keeps that lookup;
-* ``index.df_table.builds`` counts each table (re)build and no search.
+* ``index.df_table.builds`` counts each table (re)build and no search;
+* the base posting lists are built on demand only: a fused index holds
+  none, ``index.postings.builds`` stays 0 through build and fused search
+  and counts the first ``postings``-engine search, and answers do not
+  depend on whether they were built -- at 400 and at 768 features.
 """
 
 import dataclasses
@@ -30,8 +37,9 @@ import pytest
 from repro.core import (CombinedEncoder, IntervalEncoder, RoundingEncoder,
                         TrimFilter, VectorIndex)
 from repro.core.filtering import expand_mask, feature_mask
-from repro.core.postings import (build_df_table, build_postings, code_df,
-                                 df_lookup, table_df)
+from repro.core.postings import (_table_codes, build_df_table,
+                                 build_postings, code_df, df_lookup,
+                                 table_df)
 from repro.core.rerank import normalize
 from repro.core.search import _SENTINEL
 from repro.dist.shard_index import ShardedVectorIndex, _put, _ROW
@@ -47,6 +55,19 @@ _ENCODERS = {
     "rounding2": RoundingEncoder(2),
     "combined_r3_i02": CombinedEncoder(),        # int16 codes, still tabled
 }
+# the served encoder, at the widths of the benchmark's deployments
+_COMBINED = CombinedEncoder(RoundingEncoder(1), IntervalEncoder(0.1))
+_WIDTHS = (400, 768)
+
+
+def _postings_table(codes, max_abs_bucket, sentinel):
+    """The df table as the posting-range lookup fills it: ``df_lookup``
+    over the codes' sorted postings for every code the table holds."""
+    vals = _table_codes(max_abs_bucket, sentinel)
+    C = codes.shape[1]
+    q = jnp.broadcast_to(jnp.asarray(vals, codes.dtype)[:, None],
+                         (vals.size, C))
+    return np.asarray(df_lookup(build_postings(codes), q)).T
 
 
 def _probe_codes(encoder, n_columns, rng, n_random=64):
@@ -80,9 +101,11 @@ def test_table_read_equals_lookup_and_code_df(name):
     codes = jnp.asarray(codes)
     p = build_postings(codes)
     m, sentinel = encoder.max_abs_bucket, _SENTINEL[codes.dtype]
-    table = build_df_table(p, m, sentinel)
+    table = build_df_table(codes, m, sentinel)
     assert table.shape == (codes.shape[1], 2 * m + 2)
     assert table.dtype == jnp.int32
+    assert np.array_equal(np.asarray(table),
+                          _postings_table(codes, m, sentinel))
     q = jnp.asarray(_probe_codes(encoder, codes.shape[1], rng))
     got = np.asarray(table_df(table, p, q, m, sentinel))
     assert np.array_equal(got, np.asarray(df_lookup(p, q)))
@@ -114,19 +137,25 @@ def test_query_codes_stay_in_table_range(name):
 
 def _shard_dfs(sidx, qcodes):
     """Every df table of ``sidx`` (base, then each sealed segment), per
-    shard, against df_lookup over its postings and the dense count over
-    its codes (dead rows carry the sentinel)."""
+    shard, against the table the posting-range lookup fills, df_lookup over
+    its postings and the dense count over its codes (dead rows carry the
+    sentinel).  The base posting lists are compared only where the index
+    built them; reading them here would build them."""
     m = sidx.encoder.max_abs_bucket
     sentinel = int(_SENTINEL[sidx.codes.dtype])
-    parts = [(sidx.codes, sidx.post_codes, sidx.df_table)]
+    base = sidx.post_codes if sidx.has_postings else None
+    parts = [(sidx.codes, base, sidx.df_table)]
     parts += [(s.codes, s.post_codes, s.df_table) for s in sidx.segments]
     q = jnp.asarray(qcodes)
     for codes, pcodes, table in parts:
         assert table.shape[-1] == 2 * m + 2
         for s in range(sidx.n_shards):
             p = build_postings(codes[s])
-            assert np.array_equal(np.asarray(p.post_codes),
-                                  np.asarray(pcodes[s]))
+            if pcodes is not None:
+                assert np.array_equal(np.asarray(p.post_codes),
+                                      np.asarray(pcodes[s]))
+            assert np.array_equal(np.asarray(table[s]), _postings_table(
+                codes[s], m, sentinel))
             got = np.asarray(table_df(table[s], p, q, m, sentinel))
             assert np.array_equal(got, np.asarray(df_lookup(p, q)))
             assert np.array_equal(got, np.asarray(code_df(codes[s], q)))
@@ -143,11 +172,10 @@ def _live_df(sidx, qcodes):
     return (qcodes[:, None, :] == live[None]).sum(axis=1)
 
 
-def check_lifecycle(n_shards):
+def check_lifecycle(n_shards, n_feat=8):
     """Tables exact after build, delete, ingest + seal, merge."""
     rng = np.random.default_rng(11)
-    n_feat = 8
-    encoder = CombinedEncoder(RoundingEncoder(1), IntervalEncoder(0.1))
+    encoder = _COMBINED
     V = rng.normal(size=(45, n_feat)).astype(np.float32)
     Q = rng.normal(size=(5, n_feat)).astype(np.float32)
     sidx = ShardedVectorIndex.build_sharded(
@@ -173,10 +201,21 @@ def check_lifecycle(n_shards):
     sidx = sidx.merge_segments()
     assert sidx.n_segments == 1
     check("merge")
+    assert not sidx.has_postings        # no step needed the base's lists
+    sidx = sidx.compact()
+    check("compact")
 
 
 def test_tables_exact_through_lifecycle_one_shard():
     check_lifecycle(1)
+
+
+@pytest.mark.parametrize("n_feat", _WIDTHS)
+def test_tables_exact_through_lifecycle_at_deployment_widths(n_feat):
+    """The same lifecycle at 800 and 1,536 code columns: the tables
+    counted off the codes equal the postings-derived ones, sentinels,
+    tombstones after delete and merged segments included."""
+    check_lifecycle(1, n_feat)
 
 
 def _run_subprocess(script: str) -> None:
@@ -197,7 +236,7 @@ def test_tables_exact_through_lifecycle_four_shards():
         "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         "import sys\nsys.path.insert(0, 'tests')\n"
         "from test_df_table import check_lifecycle\n"
-        "check_lifecycle(4)\nprint('OK')\n")
+        "check_lifecycle(4)\ncheck_lifecycle(4, 768)\nprint('OK')\n")
 
 
 def _without_table(sidx):
@@ -282,3 +321,80 @@ def test_builds_counter_counts_rebuilds_not_searches():
     wide = rng.normal(size=(30, 16)).astype(np.float32)   # too wide: no table
     step(lambda: ShardedVectorIndex.build_sharded(
         wide, make_shard_mesh(1), encoder=RoundingEncoder(6)), 0)
+
+
+def _leaf_names(sidx):
+    return {name for name, _, _ in sidx.resident_leaves()}
+
+
+@pytest.mark.parametrize("n_feat", _WIDTHS)
+def test_fused_index_builds_postings_only_on_demand(n_feat):
+    """A fused build holds no base posting lists and builds none through
+    build, fused search and df reads; the first ``postings``-engine search
+    builds them once, and no answer depends on whether they exist."""
+    metrics = MetricsRegistry()
+    watch = CompileWatch(metrics=metrics, enabled=True)
+    builds = metrics.counter("index.postings.builds")
+    rng = np.random.default_rng(n_feat)
+    V = rng.normal(size=(48, n_feat)).astype(np.float32)
+    Q = rng.normal(size=(4, n_feat)).astype(np.float32)
+    mesh = make_shard_mesh(1)
+    trim = TrimFilter(0.05)
+    with watch.region("test"):
+        sidx = ShardedVectorIndex.build_sharded(V, mesh, encoder=_COMBINED)
+        assert not sidx.has_postings
+        assert not {"post_docs", "post_codes"} & _leaf_names(sidx)
+        fused = [sidx.search(Q, k=5, page=p, trim=trim, engine="fused")
+                 for p in (9, 96)]
+        sidx.token_df(Q)
+        assert builds.value == 0 and not sidx.has_postings
+        post = sidx.search(Q, k=5, page=96, trim=trim, engine="postings")
+        assert builds.value == 1 and sidx.has_postings
+        assert {"post_docs", "post_codes"} <= _leaf_names(sidx)
+        sidx.search(Q, k=5, page=96, trim=trim, engine="postings")
+        assert 1 <= sidx.max_df <= sidx.docs_per_shard
+        assert builds.value == 1                # cached per instance
+        again = [sidx.search(Q, k=5, page=p, trim=trim, engine="fused")
+                 for p in (9, 96)]
+        # a fresh index whose lists were read before its first search
+        other = ShardedVectorIndex.build_sharded(V, mesh, encoder=_COMBINED)
+        p = build_postings(other.codes[0])
+        assert np.array_equal(np.asarray(other.post_docs[0]),
+                              np.asarray(p.post_docs))
+        assert builds.value == 2
+        first = [other.search(Q, k=5, page=p, trim=trim, engine="fused")
+                 for p in (9, 96)]
+    for a, b, c in zip(fused, again, first):
+        for x, y, z in zip(a, b, c):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+            assert np.array_equal(np.asarray(x), np.asarray(z))
+    # page >= n_docs: every engine answers the same exact top-k
+    assert np.array_equal(np.asarray(post[0]), np.asarray(fused[1][0]))
+    assert np.array_equal(np.asarray(post[1]), np.asarray(fused[1][1]))
+
+
+def test_derived_indexes_carry_or_drop_the_postings():
+    """Mutations that leave the base codes alone keep built posting lists
+    (no second sort); a base delete drops them, and the lists sorted again
+    on demand match the new codes.  Without a build nothing carries one."""
+    metrics = MetricsRegistry()
+    watch = CompileWatch(metrics=metrics, enabled=True)
+    builds = metrics.counter("index.postings.builds")
+    rng = np.random.default_rng(4)
+    V = rng.normal(size=(40, 8)).astype(np.float32)
+    with watch.region("test"):
+        sidx = ShardedVectorIndex.build_sharded(
+            V, make_shard_mesh(1), encoder=_COMBINED, seal_threshold=4)
+        bare = sidx.add_documents(V[:5]).delete([41]).merge_segments()
+        assert not bare.has_postings and builds.value == 0
+        sidx.post_docs
+        out = sidx.add_documents(V[:5])             # seals one segment
+        assert out.n_segments == 1 and out.has_postings
+        out = out.delete([41]).merge_segments()     # appended rows only
+        assert out.has_postings and builds.value == 1
+        out = out.delete([3])                       # a base row
+        assert not out.has_postings
+        p = build_postings(out.codes[0])
+        assert np.array_equal(np.asarray(out.post_codes[0]),
+                              np.asarray(p.post_codes))
+        assert builds.value == 2

@@ -109,24 +109,38 @@ def _serve_traced(tmp_path, corpus, tracer):
 
 
 def test_engine_phases_in_order_on_the_worker_thread(tmp_path, corpus):
+    """On the worker thread each batch's phases run in order, and the
+    second batch is dispatched before the first is read back: the two
+    dispatch spans overlap, each holding its own search phases and ending
+    with its own readback."""
     spans = _serve_traced(tmp_path, corpus, Tracer(annotate=True))
-    top, inner = [], []
-    for name, s, e in spans:
-        if top and s >= top[-1][1] and e <= top[-1][2]:
-            inner.append((top[-1], name))
-        else:
-            top.append((name, s, e))
-    names = [n for n, _, _ in top]
-    one = ["repro.engine.wait", "repro.engine.batch_form",
-           "repro.engine.dispatch", "repro.engine.resolve"]
+    names = [n for n, _, _ in spans]
+    search = ["repro.search.encode", "repro.search.query_phase",
+              "repro.search.merge"]
     # 8 queries, batches of 4: two dispatches, then the wait close ends
-    assert names == one * 2 + ["repro.engine.wait"], names
-    for i in range(1, len(top)):
-        assert top[i][1] >= top[i - 1][2]       # back to back, no overlap
-    for d in (t for t in top if t[0] == "repro.engine.dispatch"):
-        kids = [n for parent, n in inner if parent == d]
-        assert kids == ["repro.search.encode", "repro.search.query_phase",
-                        "repro.search.merge", "repro.engine.readback"], kids
+    assert names == (
+        ["repro.engine.wait", "repro.engine.batch_form",
+         "repro.engine.dispatch"] + search
+        + ["repro.engine.wait", "repro.engine.batch_form",
+           "repro.engine.dispatch"] + search
+        + ["repro.engine.readback", "repro.engine.resolve",
+           "repro.engine.wait", "repro.engine.readback",
+           "repro.engine.resolve", "repro.engine.wait"]), names
+    d1, d2 = [(s, e) for n, s, e in spans if n == "repro.engine.dispatch"]
+    assert d2[0] < d1[1]                        # two batches in flight
+    # dispatch b opens before its encode and closes with its readback,
+    # before its resolve
+    encodes = [s for n, s, _ in spans if n == "repro.search.encode"]
+    readbacks = [e for n, _, e in spans if n == "repro.engine.readback"]
+    resolves = [s for n, s, _ in spans if n == "repro.engine.resolve"]
+    for (s0, e0), enc, rb, res in zip((d1, d2), encodes, readbacks,
+                                      resolves):
+        assert s0 <= enc and rb <= e0 <= res
+    # the engine's own spans never overlap one another but for dispatch
+    own = [(s, e) for n, s, e in spans if n.startswith("repro.engine.")
+           and n not in ("repro.engine.dispatch", "repro.engine.readback")]
+    for (_, e), (s, _) in zip(own, own[1:]):
+        assert s >= e
 
 
 def test_no_span_opens_without_annotation(tmp_path, corpus):

@@ -217,3 +217,38 @@ for mesh, merge in ((make_shard_mesh(4), None),
         eng.close()
 print("OK")
 """)
+
+
+@pytest.mark.parametrize("n_features", [400, 768])
+@pytest.mark.parametrize("filters", ["none", "trim", "trim+best"])
+def test_query_encode_program_equals_op_by_op_encode(n_features, filters):
+    """Both indexes encode their queries as one jitted program; its unit
+    queries, codes and mask are the op-by-op encode's, bit for bit, over
+    random rows of every scale and rows on the bucket edges."""
+    import jax.numpy as jnp
+
+    from repro.core import (BestFilter, CombinedEncoder, IntervalEncoder,
+                            RoundingEncoder)
+    from repro.core.filtering import expand_mask, feature_mask
+    from repro.core.rerank import normalize
+    from repro.core.search import encode_query_rows
+
+    enc = CombinedEncoder(RoundingEncoder(1), IntervalEncoder(0.1))
+    trim = None if filters == "none" else TrimFilter(0.05)
+    best = BestFilter(90) if filters == "trim+best" else None
+    rng = np.random.default_rng(n_features)
+    rows = [rng.normal(size=(16, n_features)) * s for s in (1e-3, 1.0, 50.0)]
+    # unit rows whose features sit on and beside the 0.1 bucket edges
+    edge = np.zeros((8, n_features))
+    edge[:, :40] = np.linspace(-1.0, 1.0, 21).repeat(2)[:40] / 5.0
+    rows.append(edge + rng.normal(size=edge.shape) * 1e-8)
+    for x in rows:
+        q = jnp.asarray(x, jnp.float32)
+        want_q = normalize(q)
+        want_c = enc.encode(want_q)
+        want_m = expand_mask(feature_mask(want_q, trim=trim, best=best),
+                             want_c.shape[-1])
+        got = encode_query_rows(q, encoder=enc, trim=trim, best=best)
+        for a, b in zip(got, (want_q, want_c, want_m)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert np.array_equal(np.asarray(a), np.asarray(b))

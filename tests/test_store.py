@@ -85,6 +85,46 @@ def _assert_bit_identical(live, rec, queries, ctx, *, leaves=True):
         assert np.array_equal(np.asarray(s1), np.asarray(s2)), (ctx, engine)
 
 
+def test_snapshot_round_trip_of_a_postings_free_index(tmp_path):
+    """An index whose base posting lists were never built commits and
+    restores without building them; the restored leaves, df tables and
+    fused answers equal the live index's, and the lists both sort on
+    demand are equal too."""
+    from repro.core import CombinedEncoder, IntervalEncoder, RoundingEncoder
+    from repro.obs.compile_watch import CompileWatch
+    from repro.obs.metrics import MetricsRegistry
+
+    metrics = MetricsRegistry()
+    watch = CompileWatch(metrics=metrics, enabled=True)
+    builds = metrics.counter("index.postings.builds")
+    V, rng = _build(n_docs=40, dims=12, seed=3)
+    Q = rng.normal(size=(4, 12)).astype(np.float32)
+    enc = CombinedEncoder(RoundingEncoder(1), IntervalEncoder(0.1))
+    with watch.region("test"):
+        live = ShardedVectorIndex.build_sharded(
+            V, make_shard_mesh(1), encoder=enc, seal_threshold=4)
+        live = live.add_documents(rng.normal(size=(6, 12))
+                                  .astype(np.float32)).delete([2, 41])
+        write_commit(str(tmp_path), live, seq=0)
+        rec = restore(latest_commit(str(tmp_path)), make_shard_mesh(1))
+        assert not live.has_postings and not rec.has_postings
+        assert builds.value == 0
+        for name in _LEAVES:
+            if name not in ("post_docs", "post_codes"):
+                assert np.array_equal(np.asarray(getattr(live, name)),
+                                      np.asarray(getattr(rec, name))), name
+        for page in (9, 2 * live.n_ids):
+            i1, s1 = live.search(Q, k=6, page=page, engine="fused")
+            i2, s2 = rec.search(Q, k=6, page=page, engine="fused")
+            assert np.array_equal(np.asarray(i1), np.asarray(i2)), page
+            assert np.array_equal(np.asarray(s1), np.asarray(s2)), page
+        assert builds.value == 0
+        for name in ("post_docs", "post_codes"):
+            assert np.array_equal(np.asarray(getattr(live, name)),
+                                  np.asarray(getattr(rec, name))), name
+        assert builds.value == 2
+
+
 # ---------------------------------------------------------------- translog
 def test_translog_append_replay_roundtrip(tmp_path):
     log = Translog(str(tmp_path))

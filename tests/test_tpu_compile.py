@@ -1,9 +1,11 @@
 """Compile the fused phase-1 kernels for a TPU v5e that is described, not
-attached, at the 1-chip share of the paper's Wikipedia deployment: 1,045,376
-docs x 400 LSA features (800 code columns under the combined encoder), a
-128-query batch, page 320.  Interpret mode hides what Mosaic refuses (an
-in-kernel sort or top_k, an unsupported shape cast, a misaligned block);
-this compile does not.  No chip is needed and nothing runs.
+attached, at the 1-chip shares of the benchmark's deployments: the paper's
+Wikipedia, 1,045,376 docs x 400 LSA features (800 code columns under the
+combined encoder) and a 128-query batch, and the eighth of a 768-d MS MARCO
+passage index, 1,105,280 docs x 768 features (1,536 columns) and a 64-query
+batch; page 320.  Interpret mode hides what Mosaic refuses (an in-kernel
+sort or top_k, an unsupported shape cast, a misaligned block); this compile
+does not.  No chip is needed and nothing runs.
 
 The topology is described inside a fixture, never while the module is
 imported: only one process at a time may load the TPU compiler's library.
@@ -17,7 +19,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.fused_phase1.kernel import (fused_phase1_pallas,
                                                fused_phase1_quant_pallas)
 
-D, N_FEATURES, C, Q, PAGE = 1_045_376, 400, 800, 128, 320
+PAGE = 320
+# (docs, features, queries) per chip of each deployment; 2 code columns
+# per feature
+SHAPES = {"wiki-1chip": (1_045_376, 400, 128),
+          "msmarco768-1chip": (1_105_280, 768, 64)}
 
 
 @pytest.fixture(scope="module")
@@ -44,7 +50,10 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_fused_fp32_kernel_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_fused_fp32_kernel_compiles_for_v5e(one_chip, shape):
+    D, n_features, Q = SHAPES[shape]
+    C = 2 * n_features
     S = lambda shape, dt: _spec(shape, dt, one_chip)
     compiled = fused_phase1_pallas.lower(
         S((D, C), jnp.int8), S((Q, C), jnp.int8), S((Q, C), jnp.float32),
@@ -52,10 +61,12 @@ def test_fused_fp32_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_int8_kernel_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_fused_int8_kernel_compiles_for_v5e(one_chip, shape):
+    D, n_features, Q = SHAPES[shape]
     S = lambda shape, dt: _spec(shape, dt, one_chip)
     compiled = fused_phase1_quant_pallas.lower(
-        S((D, N_FEATURES), jnp.int8), S((D,), jnp.float32),
-        S((D,), jnp.float32), S((Q, N_FEATURES), jnp.float32),
+        S((D, n_features), jnp.int8), S((D,), jnp.float32),
+        S((D,), jnp.float32), S((Q, n_features), jnp.float32),
         S((Q, 1), jnp.float32), S((D,), jnp.bool_), page=PAGE).compile()
     assert "tpu_custom_call" in compiled.as_text()
