@@ -18,14 +18,16 @@ the parity suite pins against).  Inventory:
 * ``bucketize``   -- fused normalize + quantize encode used at
   build/ingest: one HBM pass instead of normalize -> rounds -> casts.
 * ``fused_phase1`` -- THE query hot path (ROADMAP fused-path item):
-  phase-1 scoring and the running top-``page`` selection in ONE kernel.
-  Tiling: grid (Q/BQ, d/BD) with the doc axis minor; each step scores a
-  (BQ, BD) tile -- fp32 weighted code equality (``fused`` engine) or int8
-  quantized dot + per-row affine correction (``fused_int8`` engine, table
-  from :mod:`repro.core.quantize`) -- and folds it into a (BQ, page)
-  accumulator kept in the revisited output block: ``top_k(concat([acc,
-  tile]))``.  Stable top-k makes the streamed fold bit-equivalent to one
-  global top-k, and the C reduction is unchunked, so the fp32 path is
+  phase-1 scoring and the running top-``page`` selection in ONE kernel,
+  folded into an accumulator held in a resident output block.  fp32
+  weighted code equality (``fused`` engine): grid (d/BD, Q/BQ) with the
+  query axis minor, each doc tile widened and transposed once per batch
+  and summed per query by a depth-first pairwise tree over 8-row vregs.
+  int8 quantized dot + per-row affine correction (``fused_int8``
+  engine, table from :mod:`repro.core.quantize`): grid (Q/BQ, d/BD)
+  with the doc axis minor, one MXU dot per tile.  Stable top-k makes
+  the streamed fold bit-equivalent to one global top-k, and the fp32
+  tree adds the reference's pairs in its order, so the fp32 path is
   BIT-identical to the composed reference while never materializing the
   (Q, d) score matrix in HBM (the composed path writes + re-reads it --
   2*Q*d*4 bytes that dominate at scale; see BENCH_kernel_scale.json).

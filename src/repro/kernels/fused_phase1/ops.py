@@ -28,8 +28,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .kernel import (DEFAULT_BLOCK_D, DEFAULT_BLOCK_Q, fused_phase1_pallas,
-                     fused_phase1_quant_pallas)
+from .kernel import (DEFAULT_BLOCK_D, DEFAULT_BLOCK_Q, FP32_BLOCK_D,
+                     fused_phase1_pallas, fused_phase1_quant_pallas)
 
 _INTERPRET_ELEMENT_LIMIT = 1 << 22  # interpret mode is python-speed; cap it
 # doc-tile width of the scan fallback: 512 keeps the (Q, block, C) select
@@ -123,7 +123,7 @@ def fused_phase1(
     page: int,
     live: Optional[jnp.ndarray] = None,
     block_q: int = DEFAULT_BLOCK_Q,
-    block_d: int = DEFAULT_BLOCK_D,
+    block_d: int = FP32_BLOCK_D,
     force_pallas: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused fp32 phase-1: code-match scores + top-``page`` in one pass
@@ -141,7 +141,6 @@ def fused_phase1(
         return _finish(s, i, Q, d)
 
     block_q = min(block_q, max(Q, 1))
-    block_d = min(block_d, max(d, 1))
     pad_q = (-Q) % block_q
     qc = jnp.pad(qcodes, ((0, pad_q), (0, 0)))
     w = jnp.pad(col_weights, ((0, pad_q), (0, 0)))

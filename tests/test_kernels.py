@@ -189,6 +189,36 @@ class TestFusedPhase1Kernel:
         want = fused_phase1_ref(D, Q, W, page=page)
         _assert_fused_parity(got, want, d, (shape, dtype))
 
+    @pytest.mark.parametrize("d, q, c, block_d", [
+        # the cells' widths, full query tiles, three doc tiles of two
+        # 512-doc groups each, the last ragged
+        (2600, 128, 800, 1024), (2600, 64, 1536, 1024),
+        # code widths the tree's 8-row vregs group awkwardly: under one
+        # vreg, one exactly, one and a bit, a ragged last vreg, and trees
+        # whose half (1, 2, 4) is under a vreg
+        (700, 8, 1, 512), (700, 8, 3, 512), (700, 8, 5, 512),
+        (700, 8, 7, 512), (700, 8, 8, 512), (700, 8, 9, 512),
+        (700, 8, 23, 512), (700, 8, 100, 512),
+        # a batch that is not a multiple of the query tile
+        (1500, 13, 40, 1024),
+    ])
+    def test_tree_bit_parity(self, d, q, c, block_d):
+        """The kernel's depth-first tree over 8-row vregs adds the same
+        pairs as ref.match_scores: scores and ids bit-equal to the
+        composed oracle (computed 8 queries at a time, which cannot move
+        a bit, to keep its (Q, d, C) temporary small)."""
+        rng = np.random.default_rng(d + q + c)
+        D = jnp.asarray(rng.integers(-4, 4, size=(d, c)).astype(np.int8))
+        Q = jnp.asarray(rng.integers(-4, 4, size=(q, c)).astype(np.int8))
+        W = jnp.asarray(rng.random((q, c)).astype(np.float32))
+        live = jnp.asarray(rng.random(d) < 0.9)
+        got = fp_ops.fused_phase1(D, Q, W, page=64, live=live,
+                                  block_d=block_d, force_pallas=True)
+        parts = [fused_phase1_ref(D, Q[k:k + 8], W[k:k + 8], page=64,
+                                  live=live) for k in range(0, q, 8)]
+        want = tuple(jnp.concatenate(p) for p in zip(*parts))
+        _assert_fused_parity(got, want, d, (d, q, c, block_d))
+
     def test_auto_path_matches_ref_and_pallas(self):
         """The public wrapper's automatic backend choice (interpret for
         small problems, the lax.scan stream past the element limit) is
